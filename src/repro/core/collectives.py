@@ -25,27 +25,16 @@ from jax import lax
 
 from repro.core.mma_reduce import DEFAULT_M
 
-try:  # jax >= 0.5 promoted shard_map out of experimental
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version compat
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
 
 def shard_map_unchecked(body, *, mesh, in_specs, out_specs):
     """``shard_map`` with the replication checker off: pallas_call has no
     replication rule, so any per-device kernel launch inside a shard_map
-    body trips it. The flag was renamed across jax versions (check_rep ->
-    check_vma); try both so engine call sites stay version-portable."""
-    for kw in ("check_rep", "check_vma"):
-        try:
-            return shard_map(
-                body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                **{kw: False},
-            )
-        except TypeError:  # pragma: no cover - other jax version
-            continue
-    return shard_map(  # pragma: no cover - checker flag gone entirely
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs
+    body trips it."""
+    return shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )
 
 
@@ -84,10 +73,7 @@ def local_mma_then_psum(
 
 def axis_size_of(axis_name: str) -> int:
     """Static size of a bound mesh axis (a Python int inside shard_map)."""
-    try:
-        return lax.axis_size(axis_name)
-    except AttributeError:  # pragma: no cover - jax<0.5: psum of a literal
-        return lax.psum(1, axis_name)
+    return lax.axis_size(axis_name)
 
 
 def mesh_world_size(axis_names: Sequence[str]) -> int:
@@ -98,10 +84,14 @@ def mesh_world_size(axis_names: Sequence[str]) -> int:
     return world
 
 
-def fixed_order_combine(x: jax.Array, axis_names: Sequence[str]) -> jax.Array:
+def fixed_order_combine(
+    x: jax.Array, axis_names: Sequence[str], accum_dtype=None
+) -> jax.Array:
     """Deterministic cross-device sum: all-gather the per-device partials,
     then fold them in static device order (rank 0 first) — the PR 3
     lane-combine lifted one level up, per eq. (13)'s recurrence.
+    ``accum_dtype`` (default: ``x``'s) is the dtype of the fold: a bf16
+    gradient gathers at its own width and sums in f32.
 
     Unlike ``lax.psum`` (whose reduction order is an implementation detail of
     the collective), every device runs the identical left fold over the
@@ -110,12 +100,13 @@ def fixed_order_combine(x: jax.Array, axis_names: Sequence[str]) -> jax.Array:
     gather stays on its own mesh ring (thick-pipe-first, like
     ``hierarchical_psum``).
     """
+    accum = x.dtype if accum_dtype is None else accum_dtype
     for ax in axis_names:
         g = lax.all_gather(x, ax, axis=0, tiled=False)
         p = g.shape[0]  # static: all_gather's gathered dim is the axis size
-        acc = g[0]
+        acc = g[0].astype(accum)
         for i in range(1, p):
-            acc = acc + g[i]
+            acc = acc + g[i].astype(accum)
         x = acc
     return x
 
@@ -172,10 +163,7 @@ def ring_all_reduce(x: jax.Array, axis_name: str) -> jax.Array:
     the per-hop sends with unrelated compute, and so the compressed variant
     below can quantize the wire format per hop.
     """
-    try:
-        p = lax.axis_size(axis_name)
-    except AttributeError:  # pragma: no cover - jax<0.5: psum of a literal
-        p = lax.psum(1, axis_name)
+    p = lax.axis_size(axis_name)
     if p == 1:
         return x
     idx = lax.axis_index(axis_name)

@@ -18,15 +18,52 @@ from __future__ import annotations
 import dataclasses
 import math
 
-# --- TPU v5e hardware constants (per chip), per the assignment spec ---------
-PEAK_BF16_FLOPS = 197e12  # FLOP/s
-HBM_BW = 819e9            # bytes/s
-ICI_BW = 50e9             # bytes/s per link
 MXU_DIM = 128             # systolic array linear size
 VPU_LANES = 8 * 128       # VPU operates on (8, 128) vregs
-# An MXU pass of (128,128)x(128,128) retires in ~MXU_DIM cycles once the
-# pipeline is full; a VPU vector op retires VPU_LANES lanes/cycle.
-CLOCK_HZ = 0.94e9         # v5e core clock (approx, public)
+
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one TPU generation."""
+
+    bf16_flops: float     # FLOP/s
+    hbm_bytes_per_s: float
+    hbm_bytes: int
+    ici_bytes_per_s: float  # per link
+    mxus: int             # MXUs per chip
+
+    @property
+    def clock_hz(self) -> float:
+        """Clock implied by the bf16 peak: each MXU retires one
+        MXU_DIM x MXU_DIM multiply-accumulate (2 FLOPs each) per cycle."""
+        return self.bf16_flops / (self.mxus * 2 * MXU_DIM * MXU_DIM)
+
+
+# Keyed by ``jax.Device.device_kind``. Source: Google Cloud documentation,
+# "TPU v5e" (system architecture and chip specifications): 197 TFLOP/s bf16,
+# 16 GiB of HBM at 819 GB/s, 1,600 Gbit/s of interchip interconnect over
+# four links, one TensorCore with four MXUs per chip.
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        bf16_flops=197e12, hbm_bytes_per_s=819e9, hbm_bytes=16 * 2**30,
+        ici_bytes_per_s=50e9, mxus=4,
+    ),
+}
+
+# The chip the analytic models assume unless told otherwise.
+DEFAULT_KIND = "TPU v5 lite"
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """The peaks table entry for a device kind; an unknown kind is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)}"
+        ) from None
 
 
 # ----------------------------- paper's model --------------------------------
@@ -578,8 +615,8 @@ class IciTraffic:
 
     @property
     def time_s(self) -> float:
-        """Lower-bound gather time on the paper-model link bandwidth."""
-        return self.recv_per_device / ICI_BW
+        """Lower-bound gather time on one v5e interconnect link."""
+        return self.recv_per_device / PEAKS[DEFAULT_KIND].ici_bytes_per_s
 
     def vs_psum_recv(self) -> float:
         """Cost ratio vs an idealized reduce-scatter+gather psum of the same
@@ -712,10 +749,11 @@ class ReductionRoofline:
 
     @property
     def fused_speedup(self) -> float:
-        """VPU/MXU time ratio for a VMEM-resident (fused) reduction. ~0.8 at
-        m=128: the MXU path is near-parity on raw time -- its value is that
-        it runs on the otherwise-idle MXU, freeing 100% of VPU cycles for
-        the surrounding kernel (the contended unit in norm/softmax fusions)."""
+        """VPU/MXU time ratio for a VMEM-resident (fused) reduction. ~0.3 at
+        m=128 on v5e in this model: the MXU path is slower on raw time -- its
+        value is that it runs on the otherwise-idle MXU, freeing VPU cycles
+        for the surrounding kernel (the contended unit in norm/softmax
+        fusions). A model ratio, not a measurement."""
         return self.vpu_s / self.mxu_s if self.mxu_s else float("inf")
 
     @property
@@ -725,26 +763,29 @@ class ReductionRoofline:
         return self.mxu_s <= self.hbm_s * 1.15
 
 
-def tpu_reduction_roofline(n: int, bytes_per_el: int = 2) -> ReductionRoofline:
-    hbm_s = n * bytes_per_el / HBM_BW
+def tpu_reduction_roofline(
+    n: int, bytes_per_el: int = 2, device_kind: str = DEFAULT_KIND
+) -> ReductionRoofline:
+    peaks = peaks_for(device_kind)
+    hbm_s = n * bytes_per_el / peaks.hbm_bytes_per_s
     # VPU: streaming tree reduction retires VPU_LANES FMA lanes/cycle plus a
-    # log-depth lane-fold tail. Peak VPU ~= 2 * VPU_LANES * CLOCK ~ 1.9 TF/s.
+    # log-depth lane-fold tail. Peak VPU ~= 2 * VPU_LANES * CLOCK.
     vpu_cycles = n / VPU_LANES + 10 * math.log2(max(n, 2))
-    vpu_s = vpu_cycles / CLOCK_HZ
+    vpu_s = vpu_cycles / peaks.clock_hz
     # MXU, *throughput* model: each 2-MMA pass over k tiles of m^2=16384
     # elements issues 2k matmuls of 2*m^3 FLOPs, pipelined at chip peak.
     # Per element that is 4m FLOPs; at m=128 and 197 TF/s the MXU reduction
-    # runs within ~1.3x of the VPU's time while leaving the VPU fully idle --
-    # and both sit at/under the HBM stream time for cold bf16 operands, so
-    # the MMA encoding is bandwidth-neutral for cold data and a pure VPU
-    # offload for fused (VMEM-resident) reductions.
+    # sits within ~1.1x of the HBM stream time for cold bf16 operands while
+    # leaving the VPU idle, so in this model the MMA encoding is
+    # bandwidth-neutral for cold data and a VPU offload for fused
+    # (VMEM-resident) reductions.
     group = MXU_DIM * MXU_DIM
     mma_flops, remaining = 0.0, n
     while remaining > 1:
         k = -(-remaining // group)
         mma_flops += 2 * k * 2 * MXU_DIM**3
         remaining = k
-    mxu_s = mma_flops / PEAK_BF16_FLOPS
+    mxu_s = mma_flops / peaks.bf16_flops
     return ReductionRoofline(n, bytes_per_el, hbm_s, vpu_s, mxu_s)
 
 

@@ -31,6 +31,8 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import mma
+
 # Default linear MMA tile size. 128 is the TPU MXU systolic dimension; the
 # paper uses m=16 (WMMA API tile) / m=4 (V100 hardware tile). Tests sweep all.
 DEFAULT_M = 128
@@ -109,20 +111,20 @@ def _two_mma_pass(
     ones = jnp.ones((m, m), dtype=compute_dtype)
     a = tiles.astype(compute_dtype)
     # MMA 1: D = A x 1 + 0, accumulated at f32 like the tensor-core D matrix.
-    d = jax.lax.dot_general(
+    d = mma(
         a,
         jnp.broadcast_to(ones, a.shape),
         (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=accum_dtype,
+        accum_dtype,
     )
     # MMA 2: D' = 1 x D + 0. D re-enters at compute precision (the hardware
     # multiplies at bf16/fp16); accumulation stays f32.
     d = d.astype(compute_dtype)
-    d2 = jax.lax.dot_general(
+    d2 = mma(
         jnp.broadcast_to(ones, d.shape),
         d,
         (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=accum_dtype,
+        accum_dtype,
     )
     return d2[:, 0, 0]
 
@@ -252,11 +254,11 @@ def row_sum_mma(
     """
     length = x.shape[-1]
     ones = _ones_rhs(length, mxu_width, compute_dtype)
-    out = jax.lax.dot_general(
+    out = mma(
         x.astype(compute_dtype),
         ones,
         (((x.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=accum_dtype,
+        accum_dtype,
     )
     return out[..., 0]
 
@@ -278,11 +280,11 @@ def row_moments_mma(
     ones = _ones_rhs(length, mxu_width, compute_dtype)
     xc = x.astype(compute_dtype)
     stacked = jnp.stack([xc, (x.astype(accum_dtype) ** 2).astype(compute_dtype)], 0)
-    out = jax.lax.dot_general(
+    out = mma(
         stacked,
         ones,
         (((stacked.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=accum_dtype,
+        accum_dtype,
     )
     return out[0, ..., 0], out[1, ..., 0]
 

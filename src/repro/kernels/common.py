@@ -1,9 +1,9 @@
 """Shared Pallas kernel utilities.
 
 All kernels in this package target TPU (pl.pallas_call + BlockSpec VMEM
-tiling) and are *validated* on CPU with ``interpret=True`` -- this container
-has no TPU. ``resolve_interpret()`` picks the right mode automatically so the
-same call sites work in both worlds.
+tiling): on a TPU they run compiled, and the CPU test runs validate them with
+``interpret=True``. ``resolve_interpret()`` picks the mode from the default
+backend and refuses any other backend.
 """
 
 from __future__ import annotations
@@ -245,11 +245,43 @@ def tril_mma(m: int, dtype, k: int = 0) -> jax.Array:
     return (row + k >= col).astype(jnp.dtype(dtype))
 
 
+def mma_precision(*dtypes):
+    """Contraction precision of an engine MMA over operands of ``dtypes``.
+
+    ``HIGHEST`` when any operand is f32: by default the TPU's matrix unit
+    (in kernels and in XLA alike) rounds f32 operands to bf16, which would
+    make an f32 compute dtype a bf16 one; the CPU ignores the setting.
+    16-bit operands keep the single native pass."""
+    wide = any(jnp.dtype(d) == jnp.float32 for d in dtypes)
+    return jax.lax.Precision.HIGHEST if wide else None
+
+
+def mma(a: jax.Array, b: jax.Array, dimension_numbers,
+        accum_dtype=jnp.float32) -> jax.Array:
+    """``dot_general`` accumulating in ``accum_dtype`` at the precision its
+    operands ask for (``mma_precision``): every engine MMA."""
+    return jax.lax.dot_general(
+        a, b, dimension_numbers,
+        precision=mma_precision(a.dtype, b.dtype),
+        preferred_element_type=accum_dtype,
+    )
+
+
 def resolve_interpret(interpret: bool | None) -> bool:
-    """interpret=None -> True unless we are actually on a TPU backend."""
+    """interpret=None -> compiled on a TPU, interpreted on the CPU (the test
+    setting), and an error anywhere else: a kernel never falls back to the
+    interpreter in silence on a device it was not written for."""
     if interpret is not None:
         return interpret
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on a TPU or interpreted on the CPU; "
+        f"the default backend is {backend!r}"
+    )
 
 
 def ceil_div(a: int, b: int) -> int:
@@ -269,14 +301,9 @@ def pad_to(x: jax.Array, size: int, axis: int = 0, value=0) -> jax.Array:
     return jnp.pad(x, widths, constant_values=value)
 
 
-def compiler_params(dimension_semantics: tuple[str, ...] | None = None):
-    """Best-effort TPU compiler params; harmless under interpret mode."""
-    if dimension_semantics is None:
-        return None
-    try:
-        return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
-    except Exception:  # pragma: no cover - API drift guard
-        return None
+def compiler_params(dimension_semantics: tuple[str, ...]):
+    """TPU compiler params for a grid; harmless under interpret mode."""
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
 
 
 def vmem_scratch(shape, dtype):

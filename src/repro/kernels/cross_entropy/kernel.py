@@ -9,7 +9,8 @@ label logit gathered with a one-hot *matmul* -- reduction-as-MMA applied to
 indexing, so the gather also rides the MXU instead of a scatter/gather unit.
 
 Never materializes the (R, V) softmax; peak VMEM is one logits tile + three
-(block_rows,) carries.
+(block_rows, 1) carries. Per-row values travel as (rows, 1) columns, never
+rank-1 vectors: the chip blocks every operand in (8, 128) tiles.
 """
 
 from __future__ import annotations
@@ -28,21 +29,18 @@ NEG = -1e30
 def _mma_row_sum(mat: jax.Array, compute_dtype=jnp.bfloat16) -> jax.Array:
     d = mat.shape[-1]
     ones = jnp.ones((d, common.MXU), compute_dtype)
-    return jax.lax.dot_general(
-        mat.astype(compute_dtype),
-        ones,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0]
+    return common.mma(
+        mat.astype(compute_dtype), ones, (((1,), (0,)), ((), ()))
+    )[:, :1]
 
 
 def _ce_kernel(
     logits_ref,   # (R, BV)
-    labels_ref,   # (R,)
-    o_ref,        # (R,)
-    m_ref,        # (R,) scratch: running max
-    l_ref,        # (R,) scratch: running denominator
-    pick_ref,     # (R,) scratch: label logit
+    labels_ref,   # (R, 1)
+    o_ref,        # (R, 1)
+    m_ref,        # (R, 1) scratch: running max
+    l_ref,        # (R, 1) scratch: running denominator
+    pick_ref,     # (R, 1) scratch: label logit
     *,
     vocab: int,
     block_v: int,
@@ -62,12 +60,12 @@ def _ce_kernel(
     s = jnp.where(valid, s, NEG)
 
     # label gather as a one-hot MMA: onehot (R, BV) . s -> per-row picked
-    onehot = (vpos == labels_ref[...][:, None]) & valid
+    onehot = (vpos == labels_ref[...]) & valid
     pick_ref[...] += _mma_row_sum(jnp.where(onehot, s, 0.0), jnp.float32)
 
     m_old = m_ref[...]
-    m_new = jnp.maximum(m_old, jnp.max(s, axis=-1))
-    p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
+    m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
     l_ref[...] = l_ref[...] * jnp.exp(m_old - m_new) + _mma_row_sum(p)
     m_ref[...] = m_new
 
@@ -91,23 +89,23 @@ def cross_entropy_call(
     rp = common.round_up(rows, r)
     vp = common.round_up(vocab, block_v)
     logits_p = common.pad_to(common.pad_to(logits, rp, axis=0), vp, axis=1)
-    labels_p = common.pad_to(labels.astype(jnp.int32), rp, axis=0)
+    labels_p = common.pad_to(labels.astype(jnp.int32), rp, axis=0)[:, None]
     kernel = functools.partial(_ce_kernel, vocab=vocab, block_v=block_v)
     out = pl.pallas_call(
         kernel,
         grid=(rp // r, vp // block_v),
         in_specs=[
             pl.BlockSpec((r, block_v), lambda i, j: (i, j)),
-            pl.BlockSpec((r,), lambda i, j: (i,)),
+            pl.BlockSpec((r, 1), lambda i, j: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((r,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((rp,), jnp.float32),
+        out_specs=pl.BlockSpec((r, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rp, 1), jnp.float32),
         scratch_shapes=[
-            common.vmem_scratch((r,), jnp.float32),
-            common.vmem_scratch((r,), jnp.float32),
-            common.vmem_scratch((r,), jnp.float32),
+            common.vmem_scratch((r, 1), jnp.float32),
+            common.vmem_scratch((r, 1), jnp.float32),
+            common.vmem_scratch((r, 1), jnp.float32),
         ],
         compiler_params=common.compiler_params(("parallel", "arbitrary")),
         interpret=interpret,
     )(logits_p, labels_p)
-    return out[:rows]
+    return out[:rows, 0]

@@ -58,10 +58,12 @@ Four kernel bodies:
   ``reduce_tree``: S separate arrays enter the SAME launch as S operands
   (no packing concatenation). Each part is blocked over a shared
   sequential grid; part i's BlockSpec dwells on a clamped block index
-  outside its tile run [start_i, start_i + nblk_i) -- Pallas only re-DMAs
-  when a block index CHANGES, so the dwell costs no traffic -- and inside
-  its run the statically-unrolled body masks the part's ragged tail
-  against its true length and flushes its total at its last tile. The
+  outside its run of grid steps -- Pallas only re-DMAs when a block index
+  CHANGES, so the dwell costs no traffic -- and inside its run the
+  statically-unrolled body masks the part's ragged tail against its true
+  length and flushes its total at its last step. A multi-dimensional leaf
+  streams through its (rows, last dim) view (``part_view``): on the TPU a
+  flat view of it would be a relayout copy. The
   whole layout is trace-time static (sizes are static), so the kernel
   needs no scalar prefetch at all. Compile cost and VMEM residency are
   O(S) -- ops.py documents the fallback threshold.
@@ -89,22 +91,22 @@ MXU = common.MXU
 
 
 def _two_mma(tiles: jax.Array, compute_dtype) -> jax.Array:
-    """(R, m, m) -> (R,) via the paper's two all-ones MMAs, f32 accumulate."""
+    """(R, m, m) -> (R, 1) column of tile totals via the paper's two
+    all-ones MMAs, f32 accumulate (a column, not a rank-1 vector: the chip
+    blocks outputs in (8, 128) tiles)."""
     m = tiles.shape[-1]
     ones = common.ones_mma(m, compute_dtype)
-    d = jax.lax.dot_general(
+    d = common.mma(
         tiles.astype(compute_dtype),
         jnp.broadcast_to(ones, tiles.shape),
         (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
     )
-    d2 = jax.lax.dot_general(
+    d2 = common.mma(
         jnp.broadcast_to(ones, d.shape),
         d.astype(compute_dtype),
         (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
     )
-    return d2[:, 0, 0]
+    return d2[:, 0, :1]
 
 
 def _load_tiles(x_ref, base, n, r, m, compute_dtype, needs_mask):
@@ -119,26 +121,129 @@ def _load_tiles(x_ref, base, n, r, m, compute_dtype, needs_mask):
     the accumulate). ``needs_mask`` is static: lane geometries that cover n
     exactly skip the mask entirely, keeping the tile-multiple fast path
     op-identical to the pre-zero-copy kernels."""
-    rows = x_ref[...].reshape(r * m, m)  # lane-preserving 1D->2D relayout
-    xv = rows.astype(compute_dtype)
+    rows = _flat_rows(x_ref[...], r * m, m)
     if needs_mask:
         row = jax.lax.broadcasted_iota(jnp.int32, (r * m, m), 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (r * m, m), 1)
-        xv = jnp.where(base + row * m + col < n, xv, jnp.zeros_like(xv))
-    return xv.reshape(r, m, m)
+        rows = jnp.where(base + row * m + col < n, rows, jnp.zeros_like(rows))
+    return rows.astype(compute_dtype).reshape(r, m, m)
+
+
+def _flat_rows(flat, rows, m):
+    """Flat native block -> (rows, m) f32, the lane-preserving 1D->2D view.
+
+    The widening cast comes first: the TPU compiler relays a 1-D f32
+    vector into (8, 128) tiles but refuses the packed 16-bit 1-D layout,
+    and bf16/f16 -> f32 is exact, so the caller's later cast to the compute
+    dtype sees the same values. The tail mask also runs at f32 (the v5e
+    vector unit has no 16-bit arithmetic). A small operand arrives as its
+    whole (1, n) row (``flat_operand``) and is zero-padded here, in VMEM,
+    to the same row-major tile a flat block would give; a (rows, C) block
+    of a parts operand's 2-D view (``part_view``) holds consecutive flat
+    elements already and only splits its rows into 128 lanes."""
+    f = flat.astype(jnp.float32)
+    if f.ndim == 2 and f.shape[0] == 1:
+        f = jnp.pad(f, ((0, 0), (0, SMALL_FLAT - f.shape[1])))
+        return jnp.pad(f.reshape(SMALL_FLAT // m, m),
+                       ((0, rows - SMALL_FLAT // m), (0, 0)))
+    return f.reshape(rows, m)
+
+
+# XLA lays a 1-D array of fewer elements than this out in tiles that the
+# chip's kernel compiler does not accept for a flat block; such an operand
+# enters the launch as a (1, n) row instead (a same-size reshape, no copy).
+SMALL_FLAT = 1024
+
+
+def flat_operand(flat: jax.Array, block: int, index_map):
+    """``(operand, BlockSpec)`` for a flat native input streamed in
+    ``block``-element blocks. A small input (always a single block) enters
+    whole as a (1, n) row, which ``_flat_rows`` pads in VMEM."""
+    n = flat.shape[0]
+    if n < SMALL_FLAT:
+        return flat.reshape(1, n), pl.BlockSpec((1, n), lambda *_: (0, 0))
+    return flat, pl.BlockSpec((block,), index_map)
+
+
+# Rows of a parts operand's 2-D block: the sublane tile of 16-bit types
+# (and a multiple of f32's 8), so every ingest dtype blocks legally.
+PART_ROWS = 16
+
+
+def _part_rows(c: int) -> int:
+    """Block rows for a (rows, c) view: whole tiles per block, >= PART_ROWS."""
+    return max(PART_ROWS, MXU * MXU // c)
+
+
+def part_view(x: jax.Array) -> jax.Array:
+    """The array the parts kernel streams for one leaf.
+
+    A flat 1-D view of a multi-dimensional array is a relayout copy on the
+    TPU (its tiles span rows), so a leaf whose last dim ``c`` divides or is
+    divided evenly into whole m^2 tiles (``_part_rows``) enters as its
+    (rows, c) view instead: collapsing leading dims keeps the tiled layout,
+    and each block's rows still hold consecutive flat elements, so the
+    tiles are the flat stream's tiles. Other leaves, and leaves smaller
+    than one block, enter flat."""
+    group = MXU * MXU
+    if x.ndim >= 2:
+        c = x.shape[-1]
+        if c % MXU == 0 and (
+            group % c == 0 or c % (group // PART_ROWS) == 0
+        ) and x.size >= _part_rows(c) * c:
+            return x.reshape(-1, c)
+    return x.reshape(-1)
+
+
+def part_tiles_per_step(view: jax.Array) -> int:
+    """m^2 tiles one grid step of the parts kernel reads from ``view``."""
+    if view.ndim == 1:
+        return 1
+    c = view.shape[1]
+    return _part_rows(c) * c // (MXU * MXU)
+
+
+def _part_operand(view: jax.Array, start: int, steps: int):
+    """``(operand, BlockSpec)`` for one parts operand: its block index
+    dwells, clamped, outside the part's run of grid steps."""
+
+    def step(j):
+        return jnp.clip(j - start, 0, steps - 1)
+
+    if view.ndim == 2:
+        c = view.shape[1]
+        return view, pl.BlockSpec((_part_rows(c), c), lambda j: (step(j), 0))
+    return flat_operand(view, MXU * MXU, lambda j: (step(j),))
+
+
+def _collapse(acc: jax.Array) -> jax.Array:
+    """(m, m) f32 accumulator of row sums -> its (1, 1) total via the
+    trailing f32 MMA (1 x acc), kept as a tile: the chip stores no scalar
+    into VMEM, so a finished statistic stays a tile until ``_place`` writes
+    it into a lane-dense output row."""
+    ones = common.ones_mma(acc.shape[0], jnp.float32)
+    return common.mma(ones, acc, (((1,), (0,)), ((), ())))[:1, :1]
+
+
+def _place(shape, col, value, row):
+    """``row`` broadcast to the (1, W) ``shape`` with lane ``col`` (static
+    or a traced int) replaced by the (1, 1) ``value`` -- the vector form of
+    a scalar slot store."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return jnp.where(lanes == col, value, row)
 
 
 def tile_partials_kernel(
     x_ref, o_ref, *, n, r, m, compute_dtype, needs_mask, prologue="identity",
     epilogue=(),
 ):
-    """One grid step: (r*m*m,) flat native elements -> (r,) partials.
+    """One grid step: (r*m*m,) flat native elements -> (r, 1) partials.
 
     ``prologue`` is the trace-time elementwise map applied after the
     compute-dtype cast and tail mask, before the eq. (9) MMA -- so
     sumsq/norm2 stream the caller's raw leaf (x^2 @ 1 instead of x @ 1).
     ``prologue="moments"`` emits the paired (r, 2) partials (group sums of
-    x AND x^2) from one pass over the tile block.
+    x in lane 0 AND x^2 in lane 1) from one pass over the tile block.
 
     ``epilogue`` (a normalized scalar chain) is only passed on the FINAL
     hierarchy level, where the launch covers a single tile (r == 1) and its
@@ -148,8 +253,12 @@ def tile_partials_kernel(
     base = pl.program_id(0) * r * m * m
     tiles = _load_tiles(x_ref, base, n, r, m, compute_dtype, needs_mask)
     if prologue == "moments":
-        o_ref[:, 0] = _two_mma(tiles, compute_dtype)
-        o_ref[:, 1] = _two_mma(tiles * tiles, compute_dtype)
+        lanes = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 1)
+        o_ref[...] = jnp.where(
+            lanes == 0,
+            _two_mma(tiles, compute_dtype),
+            _two_mma(tiles * tiles, compute_dtype),
+        )
         return
     tiles = common.apply_prologue(tiles, prologue)
     o_ref[...] = common.apply_epilogue(_two_mma(tiles, compute_dtype), epilogue)
@@ -160,11 +269,8 @@ def _tile_row_sums(xv, compute_dtype):
     the single-tile eq. (9) MMA (D = X @ 1) the gather/parts bodies fold
     into their VMEM accumulators."""
     m = xv.shape[-1]
-    return jax.lax.dot_general(
-        xv,
-        common.ones_mma(m, compute_dtype),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+    return common.mma(
+        xv, common.ones_mma(m, compute_dtype), (((1,), (0,)), ((), ()))
     )
 
 
@@ -175,11 +281,8 @@ def _block_row_sums(tiles, compute_dtype):
     accumulation mode."""
     m = tiles.shape[-1]
     ones = common.ones_mma(m, compute_dtype)
-    return jax.lax.dot_general(
-        tiles,
-        jnp.broadcast_to(ones, tiles.shape),
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
+    return common.mma(
+        tiles, jnp.broadcast_to(ones, tiles.shape), (((2,), (1,)), ((0,), (0,)))
     )
 
 
@@ -225,9 +328,7 @@ def fused_accumulate_kernel(
     tiles = _load_tiles(x_ref, base, n, r, m, compute_dtype, needs_mask)
     if census:  # census BEFORE the prologue: count the raw masked values
         cacc_ref[...] += jnp.sum(
-            _block_row_sums(_tile_nonfinite(tiles, compute_dtype),
-                            compute_dtype),
-            axis=0,
+            _block_row_sums(_tile_nonfinite(tiles), CENSUS_DTYPE), axis=0
         )
     tiles = common.apply_prologue(tiles, prologue)
     d = _block_row_sums(tiles, compute_dtype)
@@ -237,16 +338,10 @@ def fused_accumulate_kernel(
     @pl.when(j == pl.num_programs(1) - 1)
     def _emit():
         if epilogue:  # static: in-launch collapse + scalar chain
-            onesf = common.ones_mma(m, jnp.float32)
-            total = jnp.dot(
-                onesf, acc_ref[...], preferred_element_type=jnp.float32
-            )
-            o_ref[0, 0] = common.apply_epilogue(total[0, 0], epilogue)
+            total = common.apply_epilogue(_collapse(acc_ref[...]), epilogue)
             if census:
-                ctotal = jnp.dot(
-                    onesf, cacc_ref[...], preferred_element_type=jnp.float32
-                )
-                o_ref[0, 1] = ctotal[0, 0]
+                total = _place(o_ref.shape, 1, _collapse(cacc_ref[...]), total)
+            o_ref[...] = jnp.broadcast_to(total, o_ref.shape)
         elif census:
             o_ref[0, 0] = acc_ref[...]
             o_ref[0, 1] = cacc_ref[...]
@@ -283,15 +378,40 @@ def fused_moments_kernel(
         o_ref[0, 1] = acc2_ref[...]
 
 
+def _split_exact(tiles: jax.Array):
+    """(r, m, m) f32 tiles -> (hi, lo), hi + lo == tiles exactly.
+
+    Each row gets a power-of-two anchor 2^(e+1) above its largest
+    magnitude. Adding and removing 3 * 2^(e+7) keeps every element inside
+    one binade, so ``hi`` is the element rounded to a multiple of
+    u = 2^(e-15): |hi| <= 2^16 u, and a row sum of m <= 256 such values
+    stays within 2^24 u, which the MMA forms exactly in any order. ``lo``
+    is the exact residue, |lo| <= u / 2. The anchor exponent is capped so
+    the shift stays finite."""
+    amax = jnp.max(jnp.abs(tiles), axis=-1, keepdims=True)
+    bits = jnp.minimum(
+        jax.lax.bitcast_convert_type(amax, jnp.int32) & 0x7F800000,
+        0x7A000000,
+    )
+    anchor = jax.lax.bitcast_convert_type(bits + (1 << 23), jnp.float32)
+    shift = anchor * 192.0
+    hi = (tiles + shift) - shift
+    return hi, tiles - hi
+
+
 def fused_kahan_kernel(
     x_ref, o_ref, acc_ref, comp_ref, *, n, r, c, m, compute_dtype, needs_mask,
     prologue="identity",
 ):
     """Fused lane with a per-lane Kahan carry in a second scratch row.
 
-    Every tile's row-sum contribution is two-summed into (acc, comp), so the
-    serial cross-tile carry -- the only part of the lane a single MMA cannot
-    compensate -- accumulates O(1) error instead of O(tiles). Both matrices
+    The MMA's own row sum of m f32 elements rounds at every step, and at
+    useful sizes those in-tile roundings, not the cross-tile carry,
+    dominate the error. So each tile is first split error-free
+    (``_split_exact``) into a coarse part whose row sums the MMA forms
+    EXACTLY and a residue below 2^-16 of the row's magnitude; both row-sum
+    matrices are then two-summed into (acc, comp), so the lane
+    accumulates O(1) rounding error instead of O(elements). Both matrices
     are emitted; the host-side combine folds acc and -comp in one
     compensated pass (Kahan's corrected sum is s - c). The elementwise
     prologues compose (a compensated in-kernel sumsq); "moments" does not
@@ -306,13 +426,16 @@ def fused_kahan_kernel(
 
     base = (j * c + pl.program_id(0)) * r * m * m
     tiles = _load_tiles(x_ref, base, n, r, m, compute_dtype, needs_mask)
-    tiles = common.apply_prologue(tiles, prologue)
-    d = _block_row_sums(tiles, compute_dtype)
-    for t in range(r):  # static unroll: every tile is a compensated add
-        y = d[t] - comp_ref[...]
-        s = acc_ref[...] + y
-        comp_ref[...] = (s - acc_ref[...]) - y
-        acc_ref[...] = s
+    tiles = common.apply_prologue(tiles, prologue).astype(jnp.float32)
+    hi, lo = _split_exact(tiles)
+    d_hi = _block_row_sums(hi, jnp.float32)
+    d_lo = _block_row_sums(lo, jnp.float32)
+    for t in range(r):  # static unroll: every tile is two compensated adds
+        for d in (d_hi[t], d_lo[t]):
+            y = d - comp_ref[...]
+            s = acc_ref[...] + y
+            comp_ref[...] = (s - acc_ref[...]) - y
+            acc_ref[...] = s
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _emit():
@@ -368,18 +491,21 @@ def reduce_tiles(
     if prologue == "moments":
         out_specs = pl.BlockSpec((r, 2), lambda i: (i, 0))
         out_shape = jax.ShapeDtypeStruct((tpad, 2), jnp.float32)
-    else:
-        out_specs = pl.BlockSpec((r,), lambda i: (i,))
-        out_shape = jax.ShapeDtypeStruct((tpad,), jnp.float32)
+    else:  # a (tpad, 1) column: the same bytes as (tpad,), legal blocks
+        out_specs = pl.BlockSpec((r, 1), lambda i: (i, 0))
+        out_shape = jax.ShapeDtypeStruct((tpad, 1), jnp.float32)
+    operand, in_spec = flat_operand(flat, r * m * m, lambda i: (i,))
     out = pl.pallas_call(
         kernel,
         grid=(blocks,),
-        in_specs=[pl.BlockSpec((r * m * m,), lambda i: (i,))],
+        in_specs=[in_spec],
         out_specs=out_specs,
         out_shape=out_shape,
         compiler_params=common.compiler_params(("parallel",)),
         interpret=interpret,
-    )(flat)
+    )(operand)
+    if prologue != "moments":
+        out = out.reshape(tpad)
     return out[:t]
 
 
@@ -493,20 +619,21 @@ def reduce_fused(
         scratch = [common.vmem_scratch((m, m), jnp.float32)]
         if census:
             scratch.append(common.vmem_scratch((m, m), jnp.float32))
+    # striping: lane ci owns blocks ci, ci+c, ci+2c, ... so concurrent
+    # lanes stream CONTIGUOUS HBM at every step (coalesced across cores).
+    operand, in_spec = flat_operand(
+        flat, r * m * m, lambda ci, j, c=c: (j * c + ci,)
+    )
     return pl.pallas_call(
         kernel,
         grid=(c, blocks_per_lane),
-        # striping: lane ci owns blocks ci, ci+c, ci+2c, ... so concurrent
-        # lanes stream CONTIGUOUS HBM at every step (coalesced across cores).
-        in_specs=[
-            pl.BlockSpec((r * m * m,), lambda ci, j, c=c: (j * c + ci,))
-        ],
+        in_specs=[in_spec],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
         compiler_params=common.compiler_params(("parallel", "arbitrary")),
         interpret=interpret,
-    )(flat)
+    )(operand)
 
 
 def segmented_gather_kernel(
@@ -575,16 +702,14 @@ def segmented_gather_kernel(
             cacc_ref[...] = jnp.zeros_like(cacc_ref)
 
     t = j * num_cores + pl.program_id(0)  # original stream position
-    xv = x_ref[...].reshape(m, m).astype(compute_dtype)
+    xv = _flat_rows(x_ref[...], m, m)
     row = jax.lax.broadcasted_iota(jnp.int32, (m, m), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (m, m), 1)
     lin = row * m + col
     mask = (lin >= lo_ref[t]) & (lin < hi_ref[t])
-    xv = jnp.where(mask, xv, jnp.zeros_like(xv))
+    xv = jnp.where(mask, xv, jnp.zeros_like(xv)).astype(compute_dtype)
     if census_offset:  # census BEFORE the prologue: count raw masked values
-        cacc_ref[...] += _tile_row_sums(
-            _tile_nonfinite(xv, compute_dtype), compute_dtype
-        )
+        cacc_ref[...] += _tile_row_sums(_tile_nonfinite(xv), CENSUS_DTYPE)
     if prologue == "moments":
         acc_ref[...] += _tile_row_sums(xv, compute_dtype)
         maybe_acc2[0][...] += _tile_row_sums(xv * xv, compute_dtype)
@@ -595,25 +720,28 @@ def segmented_gather_kernel(
 
     @pl.when(flush_ref[t] != 0)
     def _flush():
-        # one trailing MMA collapses the accumulated row-sums: 1 x acc.
-        onesf = common.ones_mma(m, jnp.float32)
-        total = jnp.dot(onesf, acc_ref[...], preferred_element_type=jnp.float32)
-        o_ref[0, pl.ds(seg_ref[t], 1)] = common.apply_epilogue(
-            total[:1, 0], epilogue
+        # one trailing MMA collapses the accumulated row-sums: 1 x acc;
+        # each slot lands in the lane-dense (1, W) row by a lane select.
+        seg = seg_ref[t]
+        row_out = o_ref[0]
+        row_out = _place(
+            row_out.shape, seg,
+            common.apply_epilogue(_collapse(acc_ref[...]), epilogue), row_out,
         )
         acc_ref[...] = jnp.zeros_like(acc_ref)
         if prologue == "moments":
-            total2 = jnp.dot(
-                onesf, maybe_acc2[0][...], preferred_element_type=jnp.float32
+            row_out = _place(
+                row_out.shape, seg + moments_offset,
+                _collapse(maybe_acc2[0][...]), row_out,
             )
-            o_ref[0, pl.ds(seg_ref[t] + moments_offset, 1)] = total2[:1, 0]
             maybe_acc2[0][...] = jnp.zeros_like(maybe_acc2[0])
         if census_offset:
-            ctotal = jnp.dot(
-                onesf, cacc_ref[...], preferred_element_type=jnp.float32
+            row_out = _place(
+                row_out.shape, seg + census_offset, _collapse(cacc_ref[...]),
+                row_out,
             )
-            o_ref[0, pl.ds(seg_ref[t] + census_offset, 1)] = ctotal[:1, 0]
             cacc_ref[...] = jnp.zeros_like(cacc_ref)
+        o_ref[0] = row_out
 
 
 def reduce_segments(
@@ -684,25 +812,24 @@ def reduce_segments(
         moments_offset=num_segments if dual else 0,
         census_offset=num_segments if census else 0,
     )
+    # the gather: the DMA source block is read from the prefetched cover
+    # map, straight off the caller's buffer.
+    operand, in_spec = flat_operand(
+        flat, m * m, lambda ci, j, src_ref, *_, c=c: (src_ref[j * c + ci],)
+    )
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(c, tiles_per_lane),
-            in_specs=[
-                # the gather: the DMA source block is read from the
-                # prefetched cover map, straight off the caller's buffer.
-                pl.BlockSpec(
-                    (m * m,),
-                    lambda ci, j, src_ref, *_, c=c: (src_ref[j * c + ci],),
-                )
-            ],
+            in_specs=[in_spec],
+            # (c, 1, W): each lane's row is a whole (1, W) block at any c
             out_specs=pl.BlockSpec(
-                (1, out_cols), lambda ci, j, *_: (ci, 0)
+                (1, 1, out_cols), lambda ci, j, *_: (ci, 0, 0)
             ),
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((c, out_cols), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((c, 1, out_cols), jnp.float32),
         compiler_params=common.compiler_params(("parallel", "arbitrary")),
         interpret=interpret,
     )(
@@ -711,32 +838,40 @@ def reduce_segments(
         flush,
         lo_in,
         hi_in,
-        flat,
-    )
+        operand,
+    ).reshape(c, out_cols)
 
 
-def _tile_nonfinite(xv, compute_dtype):
-    """(m, m) compute-dtype tile -> (m, m) 0/1 non-finite mask, ready for the
-    ones-dot fold: the finiteness CENSUS is just another masked reduction
-    riding the same tile (NaN/Inf -> 1, everything else -> 0; masked pad
-    lanes are exact zeros, hence finite, hence never counted). The 0/1 mask
-    is exact in any compute dtype and the MMA accumulates it in f32, so the
-    count is exact up to 2^24 elements per slot."""
-    return (~jnp.isfinite(xv)).astype(compute_dtype)
+# The census mask's MMA width: 0/1 is exact at any width, so it takes the
+# single native MXU pass whatever the statistic's compute dtype.
+CENSUS_DTYPE = jnp.bfloat16
+
+
+def _tile_nonfinite(xv):
+    """(m, m) tile -> (m, m) 0/1 non-finite mask, ready for the ones-dot
+    fold: the finiteness CENSUS is just another masked reduction riding the
+    same tile (NaN/Inf -> 1, everything else -> 0; masked pad lanes are
+    exact zeros, hence finite, hence never counted). The MMA accumulates
+    the mask in f32, so the count is exact up to 2^24 elements per slot.
+    The test runs at f32 (the v5e vector unit has no 16-bit compare; the
+    widening is exact)."""
+    return (~jnp.isfinite(xv.astype(jnp.float32))).astype(CENSUS_DTYPE)
 
 
 def parts_accumulate_kernel(
     *refs, layout, m, compute_dtype, prologues=None, moments_offset=0,
     slot_epilogue=(), total_chains=None, chain_offset=None, census_offset=None,
 ):
-    """S separate flat arrays -> (S,) per-segment totals, one launch.
+    """S separate arrays -> (S,) per-segment totals, one launch.
 
-    ``layout`` is the static schedule: one ``(seg, start, nblk, size)``
-    tuple per live part, assigning it the tile run [start, start + nblk) of
-    the shared sequential grid. The body is statically unrolled over parts;
-    at any grid step exactly one ``pl.when`` fires (runs are disjoint), the
-    active part's tile is masked against its true ``size`` and folded into
-    the shared accumulator, and the part's last tile flushes its total with
+    ``layout`` is the static schedule: one ``(seg, start, steps, size, k)``
+    tuple per live part, assigning it the grid-step run
+    [start, start + steps) of the shared sequential grid, k m^2 tiles per
+    step (k > 1 for a 2-D ``part_view`` operand). The body is statically
+    unrolled over parts; at any grid step exactly one ``pl.when`` fires
+    (runs are disjoint), the active part's tiles are masked against its
+    true ``size`` and folded, one tile after another in stream order, into
+    the shared accumulator, and the part's last step flushes its total with
     one trailing f32 MMA into the (static) output slot. Empty parts never
     enter the layout -- the j == 0 init leaves their slots at the additive
     identity. Everything the kernel branches on is trace-time static, so
@@ -752,7 +887,7 @@ def parts_accumulate_kernel(
 
     ``slot_epilogue`` (normalized scalar chain) maps EVERY flushed per-part
     total before its slot write. ``total_chains`` (tuple of K chains) adds
-    the TREE total: a (1,) f32 scratch (the trailing ref) accumulates the
+    the TREE total: a (1, 1) f32 scratch (the trailing ref) accumulates the
     raw flushed totals across the sequential grid -- part flush order is
     static and deterministic -- and the LAST part's flush emits chain k of
     the running cross-part total into slot ``num_slots + k``, so a whole
@@ -764,7 +899,7 @@ def parts_accumulate_kernel(
     NON-FINITE CENSUS: a second (m, m) accumulator folds the 0/1
     not-isfinite mask of every masked tile through the SAME ones-dot MMA,
     each part's flush writes its count to slot ``census_offset + seg``, a
-    (1,) scratch carries the running cross-part count, and the last part's
+    (1, 1) scratch carries the running cross-part count, and the last part's
     flush emits it into the final slot -- per-leaf and total NaN/Inf counts
     with ZERO extra input bytes (the mask is computed on the tile already in
     registers). Pad lanes are masked to exact zeros before the mask, so the
@@ -791,7 +926,7 @@ def parts_accumulate_kernel(
         cacc_ref, ctot_ref = rest[idx], rest[idx + 1]
     n_chains = len(total_chains) if total_chains else 0
     num_slots = chain_offset if chain_offset is not None else (
-        o_ref.shape[0] - n_chains
+        o_ref.shape[-1] - n_chains
     )
     j = pl.program_id(0)
 
@@ -807,72 +942,83 @@ def parts_accumulate_kernel(
             cacc_ref[...] = jnp.zeros_like(cacc_ref)
             ctot_ref[...] = jnp.zeros_like(ctot_ref)
 
-    row = jax.lax.broadcasted_iota(jnp.int32, (m, m), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (m, m), 1)
-    lin = row * m + col
-    for ref, (seg, start, nblk, size), pro in zip(part_refs, layout, prologues):
+    for ref, (seg, start, steps, size, k), pro in zip(
+        part_refs, layout, prologues
+    ):
 
-        @pl.when((j >= start) & (j < start + nblk))
+        @pl.when((j >= start) & (j < start + steps))
         def _accumulate(
-            ref=ref, seg=seg, start=start, nblk=nblk, size=size, pro=pro
+            ref=ref, seg=seg, start=start, steps=steps, size=size, k=k,
+            pro=pro,
         ):
-            valid = size - (j - start) * m * m  # ragged tail of THIS part
-            xv = ref[...].reshape(m, m).astype(compute_dtype)
-            if size % (m * m):  # static: tile-multiple parts skip the mask
-                xv = jnp.where(lin < valid, xv, jnp.zeros_like(xv))
-            if census_offset is not None:
-                # census BEFORE the prologue: count the raw (masked) values,
-                # not their squares -- same tile, one extra ones-dot MMA
-                cacc_ref[...] += _tile_row_sums(
-                    _tile_nonfinite(xv, compute_dtype), compute_dtype
-                )
-            if pro == "moments":
-                acc_ref[...] += _tile_row_sums(xv, compute_dtype)
-                acc2_ref[...] += _tile_row_sums(xv * xv, compute_dtype)
-            else:
-                acc_ref[...] += _tile_row_sums(
-                    common.apply_prologue(xv, pro), compute_dtype
-                )
+            rows = _flat_rows(ref[...], k * m, m)
+            if size % (k * m * m):  # static: whole-block parts skip the mask
+                valid = size - (j - start) * k * m * m  # THIS part's tail
+                row = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 0)
+                col = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1)
+                rows = jnp.where(row * m + col < valid, rows,
+                                 jnp.zeros_like(rows))
+            for q in range(k):  # tile by tile, in stream order
+                xv = rows[q * m:(q + 1) * m].astype(compute_dtype)
+                if census_offset is not None:
+                    # census BEFORE the prologue: count the raw (masked)
+                    # values, not their squares -- one extra ones-dot MMA
+                    cacc_ref[...] += _tile_row_sums(
+                        _tile_nonfinite(xv), CENSUS_DTYPE
+                    )
+                if pro == "moments":
+                    acc_ref[...] += _tile_row_sums(xv, compute_dtype)
+                    acc2_ref[...] += _tile_row_sums(xv * xv, compute_dtype)
+                else:
+                    acc_ref[...] += _tile_row_sums(
+                        common.apply_prologue(xv, pro), compute_dtype
+                    )
 
-            @pl.when(j == start + nblk - 1)
+            @pl.when(j == start + steps - 1)
             def _flush():
-                onesf = common.ones_mma(m, jnp.float32)
-                total = jnp.dot(
-                    onesf, acc_ref[...], preferred_element_type=jnp.float32
+                # every slot write is a lane select into the (1, W) row
+                row_out = o_ref[...]
+                total = _collapse(acc_ref[...])
+                row_out = _place(
+                    row_out.shape, seg,
+                    common.apply_epilogue(total, slot_epilogue), row_out,
                 )
-                o_ref[seg] = common.apply_epilogue(total[0, 0], slot_epilogue)
                 acc_ref[...] = jnp.zeros_like(acc_ref)
                 if pro == "moments":
-                    total2 = jnp.dot(
-                        onesf, acc2_ref[...],
-                        preferred_element_type=jnp.float32,
-                    )
-                    o_ref[seg + moments_offset] = common.apply_epilogue(
-                        total2[0, 0], slot_epilogue
+                    row_out = _place(
+                        row_out.shape, seg + moments_offset,
+                        common.apply_epilogue(_collapse(acc2_ref[...]),
+                                              slot_epilogue),
+                        row_out,
                     )
                     acc2_ref[...] = jnp.zeros_like(acc2_ref)
                 if total_chains:
                     # sequential cross-part fold of the RAW totals (f32,
                     # static part order -> deterministic, same contraction
                     # order as the host-side jnp.sum over the (S,) slots).
-                    tot_ref[0] += total[0, 0]
+                    tot_ref[...] += total
                     # layout is start-ordered, so the last layout entry
                     # flushes on the final grid step: emit the chains there.
                     if seg == layout[-1][0]:
                         for k, chain in enumerate(total_chains):
-                            o_ref[num_slots + k] = common.apply_epilogue(
-                                tot_ref[0], chain
+                            row_out = _place(
+                                row_out.shape, num_slots + k,
+                                common.apply_epilogue(tot_ref[...], chain),
+                                row_out,
                             )
                 if census_offset is not None:
-                    ctile = jnp.dot(
-                        onesf, cacc_ref[...],
-                        preferred_element_type=jnp.float32,
+                    ctile = _collapse(cacc_ref[...])
+                    row_out = _place(
+                        row_out.shape, census_offset + seg, ctile, row_out
                     )
-                    o_ref[census_offset + seg] = ctile[0, 0]
                     cacc_ref[...] = jnp.zeros_like(cacc_ref)
-                    ctot_ref[0] += ctile[0, 0]
+                    ctot_ref[...] += ctile
                     if seg == layout[-1][0]:
-                        o_ref[o_ref.shape[0] - 1] = ctot_ref[0]
+                        row_out = _place(
+                            row_out.shape, row_out.shape[-1] - 1,
+                            ctot_ref[...], row_out,
+                        )
+                o_ref[...] = row_out
 
 
 def reduce_parts(
@@ -926,21 +1072,19 @@ def reduce_parts(
             "run the moments leaf as separate 'identity'/'square' parts"
         )
     m = MXU
-    total_blocks = layout[-1][1] + layout[-1][2] if layout else 0
     n_chains = len(total_chains) if total_chains else 0
     num_out = num_segments + n_chains + ((num_segments + 1) if census else 0)
-    in_specs = [
-        pl.BlockSpec(
-            (m * m,),
-            lambda j, start=start, nblk=nblk: (
-                jnp.clip(j - start, 0, nblk - 1),
-            ),
-        )
-        for (_, start, nblk, _) in layout
-    ]
+    # the step schedule: each part's tile run, k tiles per grid step
+    schedule, ingest, total_steps = [], [], 0
+    for part, (seg, _, nblk, size) in zip(parts, layout):
+        k = part_tiles_per_step(part)
+        steps = common.ceil_div(nblk, k)
+        schedule.append((seg, total_steps, steps, size, k))
+        ingest.append(_part_operand(part, total_steps, steps))
+        total_steps += steps
     kernel = functools.partial(
         parts_accumulate_kernel,
-        layout=layout,
+        layout=tuple(schedule),
         m=m,
         compute_dtype=compute_dtype,
         prologues=prologues,
@@ -954,17 +1098,19 @@ def reduce_parts(
     if prologues is not None and "moments" in prologues:
         scratch.append(common.vmem_scratch((m, m), jnp.float32))
     if total_chains:
-        scratch.append(common.vmem_scratch((1,), jnp.float32))
+        scratch.append(common.vmem_scratch((1, 1), jnp.float32))
     if census:
         scratch.append(common.vmem_scratch((m, m), jnp.float32))
-        scratch.append(common.vmem_scratch((1,), jnp.float32))
+        scratch.append(common.vmem_scratch((1, 1), jnp.float32))
+    # a (1, num_out) row: the same bytes as (num_out,), slot-addressable
+    # by lane selects
     return pl.pallas_call(
         kernel,
-        grid=(total_blocks,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((num_out,), lambda j: (0,)),
-        out_shape=jax.ShapeDtypeStruct((num_out,), jnp.float32),
+        grid=(total_steps,),
+        in_specs=[spec for _, spec in ingest],
+        out_specs=pl.BlockSpec((1, num_out), lambda j: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, num_out), jnp.float32),
         scratch_shapes=scratch,
         compiler_params=common.compiler_params(("arbitrary",)),
         interpret=interpret,
-    )(*parts)
+    )(*[operand for operand, _ in ingest]).reshape(num_out)

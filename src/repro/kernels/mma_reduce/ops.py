@@ -45,17 +45,23 @@ MXU = common.MXU
 PARTS_KERNEL_MAX = 128
 
 
-def _ingest(x: jax.Array) -> jax.Array:
-    """Flat native-dtype view of ``x`` for zero-copy kernel ingestion.
+def _native(x: jax.Array) -> jax.Array:
+    """``x`` in a dtype the kernels ingest directly.
 
     bf16/f16/f32 stream straight from the caller's buffer; anything the MXU
     cannot read natively (f64, ints, bools) is pre-cast to f32 -- the one
     documented staging copy left, and one the planner already routes away
     from the Pallas backends (ints go to xla)."""
-    flat = x.reshape(-1)
-    if not common.native_ingest_dtype(flat.dtype):
-        flat = flat.astype(jnp.float32)
-    return flat
+    if common.native_ingest_dtype(x.dtype):
+        return x
+    return x.astype(jnp.float32)
+
+
+def _ingest(x: jax.Array) -> jax.Array:
+    """Flat native-dtype view of ``x`` for zero-copy kernel ingestion (a
+    same-size reshape; on the TPU a relayout copy for arrays of rank >= 2,
+    which the parts kernel avoids through ``kernel.part_view``)."""
+    return _native(x).reshape(-1)
 
 
 def combine_lane_partials(partials: jax.Array) -> jax.Array:
@@ -70,15 +76,14 @@ def combine_lane_partials(partials: jax.Array) -> jax.Array:
     """
     c, m, _ = partials.shape
     onesf = common.ones_tile(m, "float32")  # cached host-side constant
-    d = jax.lax.dot_general(
+    d = common.mma(
         jnp.broadcast_to(onesf, partials.shape),
         partials,
         (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
     )
     lane = d[:, 0, 0]  # (C,) per-lane totals
-    return jnp.dot(
-        jnp.ones((c,), jnp.float32), lane, preferred_element_type=jnp.float32
+    return common.mma(
+        jnp.ones((c,), jnp.float32), lane, (((0,), (0,)), ((), ()))
     )
 
 
@@ -869,8 +874,8 @@ def mma_sum_parts_pallas(
             "run the moments leaf as separate 'identity'/'square' parts"
         )
     out_slots = (2 * nseg) if dual else nseg
-    flats = [_ingest(p) for p in parts]
-    layout = parts_layout([f.size for f in flats], MXU * MXU)
+    views = [_k.part_view(_native(p)) for p in parts]
+    layout = parts_layout([f.size for f in views], MXU * MXU)
     if not layout:  # every part empty
         per = common.apply_epilogue(
             jnp.zeros((out_slots,), jnp.float32), slot_epilogue
@@ -893,14 +898,14 @@ def mma_sum_parts_pallas(
     if trace is not None:
         trace.append(
             parts_trace(
-                [f.size for f in flats],
-                [f.dtype.itemsize for f in flats],
+                [f.size for f in views],
+                [f.dtype.itemsize for f in views],
                 pros,
                 extra_slots=n_chains,
                 census=census,
             )
         )
-    live = [flats[s] for (s, _, _, _) in layout]
+    live = [views[s] for (s, _, _, _) in layout]
     return _k.reduce_parts(
         live,
         layout,

@@ -35,12 +35,7 @@ def _mma_row_sum(mat: jax.Array, compute_dtype) -> jax.Array:
     """(R, d) -> (R,) row sums via one all-ones MMA, f32 accumulation."""
     d = mat.shape[-1]
     ones = jnp.ones((d, MXU), compute_dtype)
-    out = jax.lax.dot_general(
-        mat.astype(compute_dtype),
-        ones,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    out = common.mma(mat.astype(compute_dtype), ones, (((1,), (0,)), ((), ())))
     return out[:, 0]
 
 

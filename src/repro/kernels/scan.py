@@ -38,7 +38,7 @@ from jax.experimental import pallas as pl
 
 from repro.core import cost_model
 from repro.kernels import common
-from repro.kernels.mma_reduce.kernel import _load_tiles
+from repro.kernels.mma_reduce.kernel import _load_tiles, flat_operand
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,10 +59,9 @@ class ScanTrace:
 
 
 def _matmul(a, b):
-    """Plain (m, m) @ (m, m) with f32 accumulation -- every scan MMA."""
-    return jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    """Plain (m, m) @ (m, m) with f32 accumulation -- every scan MMA (the
+    f32 carry-down ``lower @ t1`` at full f32 width, ``common.mma``)."""
+    return common.mma(a, b, (((1,), (0,)), ((), ())))
 
 
 def scan_kernel(
@@ -93,21 +92,24 @@ def scan_kernel(
 
     @pl.when(j == 0)
     def _reset():
-        carry_ref[0, 0] = jnp.float32(0.0)
+        carry_ref[...] = jnp.zeros_like(carry_ref)
 
     tiles = _load_tiles(x_ref, base, n, r, m, compute_dtype, needs_mask)
     ones = common.ones_mma(m, compute_dtype)
     lower = common.tril_mma(m, jnp.float32, k=-1)
     upper = common.triu_mma(m, compute_dtype, k=0 if inclusive else 1)
 
-    running = carry_ref[0, 0]
+    # the carry is a (1, 1) tile, never a scalar: the chip stores no
+    # scalar into VMEM, and the tile form broadcasts into the output add.
+    running = carry_ref[...]
+    corner = slice(m - 1, m)
     carries, downs = [], []
     for t in range(r):
         t1 = _matmul(tiles[t], ones)
         down = _matmul(lower, t1)
         carries.append(running)
         downs.append(down)
-        running = running + (down[m - 1, m - 1] + t1[m - 1, m - 1])
+        running = running + (down[corner, corner] + t1[corner, corner])
 
     active = jnp.logical_and(j >= start, j < end)
 
@@ -122,7 +124,7 @@ def scan_kernel(
 
     @pl.when(j < end)
     def _advance():
-        carry_ref[0, 0] = running
+        carry_ref[...] = running
 
 
 def scan_geometry(n: int, m: int, tiles_per_block: int, num_cores: int):
@@ -189,12 +191,14 @@ def mma_scan_pallas(
         n=n, r=r, m=m, bpl=bpl, compute_dtype=cd, out_dtype=flat.dtype,
         inclusive=inclusive, needs_mask=needs_mask,
     )
+    operand, in_spec = flat_operand(
+        flat, block,
+        lambda ci, j, bpl=bpl: (jnp.minimum(j, (ci + 1) * bpl - 1),),
+    )
     out = pl.pallas_call(
         kernel,
         grid=(c, c * bpl),
-        in_specs=[pl.BlockSpec(
-            (block,), lambda ci, j, bpl=bpl: (jnp.minimum(j, (ci + 1) * bpl - 1),)
-        )],
+        in_specs=[in_spec],
         out_specs=pl.BlockSpec(
             (block,),
             lambda ci, j, bpl=bpl: (jnp.clip(j, ci * bpl, (ci + 1) * bpl - 1),),
@@ -203,7 +207,7 @@ def mma_scan_pallas(
         scratch_shapes=[common.vmem_scratch((1, 1), jnp.float32)],
         compiler_params=common.compiler_params(("parallel", "arbitrary")),
         interpret=common.resolve_interpret(interpret),
-    )(flat)
+    )(operand)
     return out[:n].reshape(x.shape).astype(x.dtype)
 
 
@@ -235,7 +239,8 @@ def mma_scan_jnp(
     chunks = chunks.reshape(x.shape[:-1] + (k, m)).astype(cd)
     upper = jnp.asarray(common.triu_tile(m, cd.name, 0 if inclusive else 1))
     rowpref = jnp.einsum(
-        "...km,mn->...kn", chunks, upper, preferred_element_type=jnp.float32
+        "...km,mn->...kn", chunks, upper, preferred_element_type=jnp.float32,
+        precision=common.mma_precision(cd),
     )
     totals = rowpref[..., m - 1]
     if not inclusive:

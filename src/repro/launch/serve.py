@@ -33,6 +33,7 @@ import numpy as np
 
 from repro import reduce as R
 from repro.configs import get_arch
+from repro.launch.device import print_device_report, use_compile_cache
 from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.models import init_params, make_caches
 from repro.models.frontends import synth_image_embeds
@@ -227,6 +228,9 @@ class GuardedEngine(Engine):
 
 
 def main(argv=None):
+    """Serve from the command line. Returns the generated token lists, or
+    under ``--guard`` the runtime's per-request results and its metrics
+    snapshot."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--tiny", action="store_true")
@@ -253,6 +257,8 @@ def main(argv=None):
                     help="atomic JSON ServeMetrics export path")
     args = ap.parse_args(argv)
 
+    use_compile_cache()
+    print_device_report()
     if args.reduce_backend:
         R.set_default_backend(args.reduce_backend)
     cfg = get_arch(args.arch, tiny=args.tiny)
@@ -299,7 +305,7 @@ def main(argv=None):
               f"breaker_trips={snap['breaker_trips']} "
               f"p50={snap['token_latency_p50_s'] * 1e3:.1f}ms "
               f"p99={snap['token_latency_p99_s'] * 1e3:.1f}ms")
-        return results
+        return results, snap
     eng = Engine(cfg, s_max, args.batch_slots)
     outs = eng.serve(reqs, args.max_new)
     dt = time.time() - t0

@@ -92,6 +92,19 @@ def _make_grads_fn(cfg, tcfg, mesh, param_shardings, bspec):
             mtoks = jax.lax.with_sharding_constraint(
                 mtoks, NamedSharding(mesh, P(None, bspec))
             )
+        if n_micro == 1:
+            # nothing to accumulate: the grads keep the dtype the backward
+            # pass produced (the optimizer upcasts per element). An f32
+            # accumulator copy would be materialized whole for a kernel
+            # that reads every leaf in one launch -- 4.7 GB at olmo-1b.
+            (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                params, mtoks[0], None if mctx is None else mctx[0]
+            )
+            if param_shardings is not None:
+                grads = jax.tree.map(
+                    jax.lax.with_sharding_constraint, grads, param_shardings
+                )
+            return grads, loss
 
         grad_zero = jax.tree.map(
             lambda p: jnp.zeros(p.shape, jnp.float32), params
@@ -217,6 +230,21 @@ def make_mesh_guarded_train_step(
     (axis,) = mesh.axis_names
     compute_grads = _make_grads_fn(cfg, tcfg, None, None, None)
 
+    def mean_over_mesh(g):
+        """The data-parallel mean of one grad leaf: gathered at its own
+        width, folded in f32 in device order, rounded once back (an f32
+        mean of every leaf would be held whole for the optimizer's
+        one-launch statistic). Stacked layer leaves go one layer at a time,
+        so a gathered copy is one layer's size, not the stack's."""
+        def fold(x):
+            world = coll.mesh_world_size((axis,))
+            total = coll.fixed_order_combine(
+                x, (axis,), accum_dtype=jnp.float32
+            )
+            return (total / world).astype(x.dtype)
+
+        return jax.lax.map(fold, g) if g.ndim >= 3 else fold(g)
+
     def body(params, opt_state, guard_state, batch):
         batch = dict(batch)
         scale = batch.pop("chaos_scale", None)
@@ -224,10 +252,8 @@ def make_mesh_guarded_train_step(
         if scale is not None:
             s = jnp.reshape(scale, (-1,))[0]
             grads = jax.tree.map(lambda g: g * s.astype(g.dtype), grads)
+        grads = jax.tree.map(mean_over_mesh, grads)
         world = coll.mesh_world_size((axis,))
-        grads = jax.tree.map(
-            lambda g: coll.fixed_order_combine(g, (axis,)) / world, grads
-        )
         loss = coll.fixed_order_combine(loss, (axis,)) / world
         new_p, new_opt, new_guard, metrics = optim.guarded_apply_updates(
             params, grads, opt_state, tcfg, loss=loss, guard=guard_state,

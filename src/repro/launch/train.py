@@ -23,7 +23,8 @@ from repro import reduce as R
 from repro.checkpoint import CheckpointManager
 from repro.configs import TrainConfig, get_arch
 from repro.data import Prefetcher, ShardInfo, SyntheticLM
-from repro.launch.mesh import make_data_mesh
+from repro.launch.device import print_device_report, use_compile_cache
+from repro.launch.mesh import make_data_mesh, replicated
 from repro.launch.steps import (
     make_jitted_guarded_train_step,
     make_jitted_train_step,
@@ -42,10 +43,25 @@ from repro.runtime import (
 
 def build(cfg, tcfg, batch: int, seq: int, mesh=None, *, guard=False,
           spike_z: float = 6.0, data_mesh=None):
-    params, axes = init_params(jax.random.PRNGKey(tcfg.seed), cfg)
-    opt_state = optim.init_state(
-        params, fused_second_moment=tcfg.fused_second_moment
-    )
+    key = jax.random.PRNGKey(tcfg.seed)
+
+    def init_opt(p):
+        return optim.init_state(
+            p, fused_second_moment=tcfg.fused_second_moment
+        )
+
+    if data_mesh is None:
+        params, _ = init_params(key, cfg)
+        opt_state = init_opt(params)
+    else:
+        # born replicated on every mesh device: no single-device copy to
+        # spread (it would double device 0's share), and the step sees one
+        # input placement from its first call, so it compiles once
+        rep = replicated(data_mesh)
+        params = jax.jit(
+            lambda k: init_params(k, cfg)[0], out_shardings=rep
+        )(key)
+        opt_state = jax.jit(init_opt, out_shardings=rep)(params)
     # donate_argnums: params and opt_state update IN PLACE (their buffers
     # are reused for the outputs) -- callers rebind both from the return
     if data_mesh is not None:
@@ -60,6 +76,8 @@ def build(cfg, tcfg, batch: int, seq: int, mesh=None, *, guard=False,
 
 
 def main(argv=None):
+    """Train from the command line; returns one record per taken step
+    (``_step_record``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--tiny", action="store_true")
@@ -101,12 +119,13 @@ def main(argv=None):
         help="guarded step: consecutive skipped steps before rollback",
     )
     ap.add_argument(
-        "--mesh", action="store_true",
-        help="mesh-aware guard: data-parallel guarded step over every "
-        "visible device under shard_map with the deterministic fixed-order "
-        "gradient combine, so the skip/rollback decisions are bit-identical "
-        "on every replica (requires --guard; --batch must divide the "
-        "device count)",
+        "--mesh", nargs="?", type=int, const=0, default=None,
+        metavar="DEVICES",
+        help="mesh-aware guard: data-parallel guarded step over DEVICES "
+        "devices (every visible device when omitted) under shard_map with "
+        "the deterministic fixed-order gradient combine, so the "
+        "skip/rollback decisions are bit-identical on every replica "
+        "(requires --guard; --batch must divide the device count)",
     )
     ap.add_argument(
         "--chaos", type=float, default=0.0,
@@ -136,15 +155,17 @@ def main(argv=None):
     )
     args = ap.parse_args(argv)
 
-    if args.mesh and not args.guard:
+    use_compile_cache()
+    print_device_report()
+    if args.mesh is not None and not args.guard:
         ap.error("--mesh requires --guard")
     if args.chaos and not args.guard:
         ap.error("--chaos requires --guard")
     if args.reduce_backend:
         R.set_default_backend(args.reduce_backend)
     data_mesh = None
-    if args.mesh:
-        data_mesh = make_data_mesh()
+    if args.mesh is not None:
+        data_mesh = make_data_mesh(args.mesh or None)
         world = int(data_mesh.devices.size)
         if args.batch % world:
             ap.error(f"--batch {args.batch} must divide {world} devices")
@@ -181,8 +202,14 @@ def main(argv=None):
 
     ckpt = CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None
     guard = PreemptionGuard()
-    guard_state = optim.init_guard_state(args.spike_window) if args.guard \
-        else None
+
+    def fresh_guard_state():
+        g = optim.init_guard_state(args.spike_window)
+        return g if data_mesh is None else jax.device_put(
+            g, replicated(data_mesh)
+        )
+
+    guard_state = fresh_guard_state() if args.guard else None
     step_guard = StepGuard(args.max_bad_steps) if args.guard else None
     chaos = None
     if args.chaos > 0:
@@ -212,7 +239,7 @@ def main(argv=None):
         ckpt.save(0, (params, opt_state),
                   extra={"data_step": data.state()["step"]})
 
-    losses = []
+    history = []
     t0 = time.time()
     step = start_step
     while step < args.steps:
@@ -253,8 +280,8 @@ def main(argv=None):
             params, opt_state, guard_state, metrics = out
         else:
             params, opt_state, metrics = out
-        losses.append(float(metrics["loss"]))
         step += 1
+        history.append(_step_record(step, metrics, params))
         if step % args.log_every == 0:
             dt = (time.time() - t0) / args.log_every
             extra = ""
@@ -264,7 +291,7 @@ def main(argv=None):
                     f" skips {int(guard_state.skipped)}"
                 )
             print(
-                f"step {step:5d} loss {losses[-1]:.4f} "
+                f"step {step:5d} loss {history[-1]['loss']:.4f} "
                 f"gnorm {float(metrics['grad_norm']):.3f} "
                 f"lr {float(metrics['lr']):.2e} {dt*1e3:.0f} ms/step"
                 + extra
@@ -272,7 +299,7 @@ def main(argv=None):
             t0 = time.time()
         skipped = False
         if step_guard is not None:
-            skipped = float(metrics["skipped"]) > 0.0
+            skipped = history[-1]["skipped"]
             step_guard.record(skipped)
             if gmetrics is not None:
                 gmetrics.record_step(
@@ -291,7 +318,7 @@ def main(argv=None):
                         back, (params, opt_state)
                     )
                     data.seek(ckpt.manifest(back)["extra"]["data_step"])
-                    guard_state = optim.init_guard_state(args.spike_window)
+                    guard_state = fresh_guard_state()
                     step_guard.reset()
                     step_guard.rollbacks += 1
                     if gmetrics is not None:
@@ -324,8 +351,26 @@ def main(argv=None):
         ckpt.wait()
     if prefetch is not None:
         prefetch.close()
-    print(f"final loss {losses[-1]:.4f} (first {losses[0]:.4f})")
-    return losses
+    print(f"final loss {history[-1]['loss']:.4f} "
+          f"(first {history[0]['loss']:.4f})")
+    return history
+
+
+def _step_record(step: int, metrics: dict, params) -> dict:
+    """One taken step as plain Python numbers: what ``main`` returns per
+    step. ``param_devices`` is the fewest devices any parameter leaf is
+    placed on -- a data-parallel step keeps a replica on every mesh
+    device."""
+    return {
+        "step": step,
+        "loss": float(metrics["loss"]),
+        "grad_norm": float(metrics["grad_norm"]),
+        "skipped": float(metrics.get("skipped", 0.0)) > 0.0,
+        "nonfinite": float(metrics.get("nonfinite", 0.0)),
+        "param_devices": min(
+            len(p.sharding.device_set) for p in jax.tree.leaves(params)
+        ),
+    }
 
 
 if __name__ == "__main__":

@@ -38,19 +38,11 @@ def shard_map_specs(fn, in_specs, out_specs):
     with explicitly-local dispatch/combine regions."""
     if _ACT_SHARDING is None:
         return None
-    from repro.core.collectives import shard_map  # version-compat resolution
+    from repro.core.collectives import shard_map_unchecked
 
-    mesh = _ACT_SHARDING.mesh
-    try:
-        return shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except TypeError:  # older jax: check_rep
-        return shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+    return shard_map_unchecked(
+        fn, mesh=_ACT_SHARDING.mesh, in_specs=in_specs, out_specs=out_specs
+    )
 
 
 def batch_axis_entry():

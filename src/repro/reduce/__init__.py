@@ -70,6 +70,7 @@ from repro.reduce.plan import (  # noqa: F401
     ReducePlan,
     ScanPlan,
     autotune,
+    autotune_failures,
     backend_for_flags,
     default_backend,
     plan_cache_clear,

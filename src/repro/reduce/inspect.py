@@ -23,10 +23,7 @@ from typing import Iterator
 
 import jax
 
-try:  # jax >= 0.4.x exposes the public aliases under jax.extend
-    from jax.extend import core as _core
-except ImportError:  # pragma: no cover - older jax
-    from jax import core as _core  # type: ignore
+from jax.extend import core as _core
 
 # Primitives that materialize a staging copy of their operand when they run
 # at stream size outside the kernel. (reshape is absent on purpose: a

@@ -53,6 +53,7 @@ import functools
 import math
 import os
 import time
+import warnings
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
@@ -77,6 +78,10 @@ _default_backend: Optional[str] = None
 # autotune()'s winners, keyed like the plan cache (shape, dtype, kind, axis,
 # segments); consulted by _plan_for_cached when the backend is auto-selected.
 _TUNED: Dict[Tuple, "ReducePlan"] = {}
+
+# autotune()'s candidates that raised, same keys: ((plan, error), ...). A
+# kernel the device refuses is a finding, not a silent loss of the race.
+_TUNE_FAILURES: Dict[Tuple, Tuple] = {}
 
 # Backends a circuit breaker (or operator) has taken out of AUTO rotation --
 # see quarantine_backend(). Explicit pins (backend= / plan=) still select a
@@ -382,7 +387,9 @@ def _auto_backend(shape, dtype, *, kind: str, axis, m: int, segments=None) -> st
     # hierarchical relaunch; EXPERIMENTS.md): take it whenever the roofline
     # says the MMA encoding is bandwidth-neutral, else stay paper-faithful.
     if jax.default_backend() == "tpu":
-        rl = cost_model.tpu_reduction_roofline(n)
+        rl = cost_model.tpu_reduction_roofline(
+            n, device_kind=jax.devices()[0].device_kind
+        )
         return "pallas_fused" if rl.mxu_bandwidth_neutral else "pallas_hier"
     # Off-TPU (CPU/interpret) the Pallas kernels run but only emulate; the
     # algorithmic path is the fast default. Explicit overrides still select
@@ -533,6 +540,7 @@ def plan_cache_clear(clear_tuned: bool = False) -> None:
     _scan_plan_cached.cache_clear()
     if clear_tuned:
         _TUNED.clear()
+        _TUNE_FAILURES.clear()
 
 
 # ------------------------------- scan plans ----------------------------------
@@ -692,6 +700,20 @@ def scan_plan_cache_info():
     return _scan_plan_cached.cache_info()
 
 
+def autotune_failures(
+    shape: Sequence[int], dtype, *, kind: str = "sum", axis=None,
+    segments: Optional[int] = None,
+) -> Tuple:
+    """``((plan, error), ...)`` for the candidates that raised in the last
+    ``autotune`` of this problem (empty if none did or it never ran)."""
+    shape_t = tuple(int(s) for s in shape)
+    axis_t = _norm_axis_arg(axis, len(shape_t))
+    return _TUNE_FAILURES.get(
+        _problem_key(shape_t, str(jnp.dtype(dtype)), kind, axis_t, segments),
+        (),
+    )
+
+
 def autotune(
     shape: Sequence[int],
     dtype,
@@ -715,8 +737,9 @@ def autotune(
     an auto-selected backend for this problem returns it. With ``segments=N`` the timed workload is the real
     segmented pass -- ``reduce_many`` over ``shape`` split into N equal
     pieces -- so ``sum_segments`` boundary handling is part of what is
-    measured. Returns the winning plan. Candidates that fail to compile or
-    run are skipped (e.g. kernel backends with a pinned m != 128).
+    measured. Returns the winning plan. A candidate that fails to compile
+    or run loses the race, with a warning, and is recorded with its error
+    (``autotune_failures``).
     """
     from repro.reduce import api as _api  # deferred: api imports this module
     from repro.reduce import backends as _backends  # deferred, same reason
@@ -741,6 +764,7 @@ def autotune(
         )
     best: Optional[ReducePlan] = None
     best_t = math.inf
+    failures = []
     for name in backends:
         is_pallas = name.startswith("pallas")
         tpbs = tuple(tiles_per_block_candidates) if is_pallas else (None,)
@@ -773,16 +797,21 @@ def autotune(
                     t0 = time.perf_counter()
                     jax.block_until_ready(fn(*x) if segments else fn(x))
                     elapsed = min(elapsed, time.perf_counter() - t0)
-            except Exception:
+            except Exception as e:
+                error = f"{type(e).__name__}: {e}"
+                failures.append((cand, error))
+                warnings.warn(f"autotune: candidate {cand} raised {error}")
                 continue
             if elapsed < best_t:
                 best, best_t = cand, elapsed
+    key = _problem_key(shape_t, str(dt), kind, axis_t, segments)
+    _TUNE_FAILURES[key] = tuple(failures)
     if best is None:
         raise RuntimeError(
             f"autotune: no candidate backend ran for shape={shape_t} "
-            f"dtype={dt} kind={kind!r}"
+            f"dtype={dt} kind={kind!r}: {[e for _, e in failures]}"
         )
-    _TUNED[_problem_key(shape_t, str(dt), kind, axis_t, segments)] = best
+    _TUNED[key] = best
     _plan_for_cached.cache_clear()  # cached auto plans may now be stale
     _scan_plan_cached.cache_clear()
     return best

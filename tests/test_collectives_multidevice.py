@@ -101,11 +101,12 @@ def test_sharded_train_step_runs_on_mesh():
     import dataclasses
     from repro.configs import TINY_ARCHS, TrainConfig
     from repro.launch import sharding as SH
+    from repro.launch.mesh import make_mesh
     from repro.launch.steps import make_train_step
     from repro.models import init_params, context as CTX
     from repro import optim
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     CTX.set_activation_sharding(NamedSharding(mesh, P("data", None, None)))
     cfg = TINY_ARCHS["internlm2-1.8b"]
     params, axes = init_params(jax.random.PRNGKey(0), cfg)

@@ -96,3 +96,44 @@ def test_model_table_rows():
     assert len(rows) == 2
     for r in rows:
         assert r["speedup"] == pytest.approx(r["speedup_closed_form"], rel=1e-9)
+
+
+def test_peaks_table_keyed_by_device_kind():
+    v5e = cm.peaks_for("TPU v5 lite")
+    assert (v5e.bf16_flops, v5e.hbm_bytes_per_s, v5e.hbm_bytes) == (
+        197e12, 819e9, 16 * 2**30
+    )
+    # the clock the bf16 peak implies: 4 MXUs x 128 x 128 MACs per cycle
+    assert v5e.clock_hz == pytest.approx(1.503e9, rel=1e-3)
+    with pytest.raises(ValueError, match="no published peaks"):
+        cm.peaks_for("TPU v99")
+    with pytest.raises(ValueError, match="no published peaks"):
+        cm.tpu_reduction_roofline(1 << 20, device_kind="TPU v99")
+
+
+def test_planner_tpu_route_refuses_an_unknown_kind(monkeypatch):
+    """On a TPU the auto route reads the peaks of the chip it runs on; a
+    kind missing from the table is an error, not the v5e default."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.reduce import plan
+
+    n = 1 << 22
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        jax, "devices",
+        lambda *a: [types.SimpleNamespace(platform="tpu",
+                                          device_kind="TPU v5 lite")],
+    )
+    assert plan._auto_backend((n,), jnp.float32, kind="sum", axis=None,
+                              m=128) == "pallas_fused"
+    monkeypatch.setattr(
+        jax, "devices",
+        lambda *a: [types.SimpleNamespace(platform="tpu",
+                                          device_kind="TPU v99")],
+    )
+    with pytest.raises(ValueError, match="TPU v99"):
+        plan._auto_backend((n,), jnp.float32, kind="sum", axis=None, m=128)

@@ -367,3 +367,64 @@ def test_multicore_kahan_single_launch_and_accurate(num_cores, rng):
         - exact
     )
     assert e_kahan <= e_native + 1e-9
+
+
+@pytest.mark.parametrize("backend,want", [("cpu", True), ("tpu", False),
+                                          ("gpu", None)])
+def test_resolve_interpret_from_the_default_backend(backend, want,
+                                                    monkeypatch):
+    """Interpreted on the CPU, compiled on a TPU, refused elsewhere: no
+    backend silently falls back to the interpreter."""
+    from repro.kernels import common
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if want is None:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            common.resolve_interpret(None)
+    else:
+        assert common.resolve_interpret(None) is want
+    assert common.resolve_interpret(True) is True  # explicit always wins
+
+
+F32_MMA_CASES = {
+    "fused_f32": lambda x: R.reduce(x, backend="pallas_fused",
+                                    compute_dtype="float32"),
+    "fused_bf16": lambda x: R.reduce(x.astype(jnp.bfloat16),
+                                     backend="pallas_fused"),
+    "hier_f32": lambda x: R.reduce(x, backend="pallas_hier",
+                                   compute_dtype="float32"),
+    "kahan": lambda x: R.reduce(x, backend="pallas_fused", precision="kahan",
+                                compute_dtype="float32"),
+    "tree_census_fork": lambda x: R.reduce_tree(
+        [x, x[:300]], "norm2", backend="pallas_fused", census=True,
+        epilogue=[(), ("clip_coeff", 1.0)]),
+    "many": lambda x: R.reduce_many(
+        [x, x[:300].astype(jnp.bfloat16)], backend="pallas_fused",
+        kind="sumsq"),
+    "scan_f32": lambda x: R.scan(x, backend="pallas_fused"),
+    "scan_bf16": lambda x: R.scan(x.astype(jnp.bfloat16),
+                                  backend="pallas_fused"),
+    "mma_jnp_norm2": lambda x: R.reduce(x, kind="norm2", backend="mma_jnp"),
+    "mma_jnp_scan_f32": lambda x: R.scan(x[:4096].reshape(4, -1),
+                                          backend="mma_jnp"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(F32_MMA_CASES))
+def test_f32_mma_operands_contract_at_highest_precision(name):
+    """On the TPU a dot at default precision rounds f32 operands to bf16
+    (interpret mode and the CPU cannot show it). Every engine MMA with an
+    f32 operand asks for HIGHEST; 16-bit-only MMAs keep the native pass."""
+    from repro.reduce import inspect as rinspect
+
+    x = jnp.ones((3 * 128 * 128 + 5,), jnp.float32)
+    jaxpr = jax.make_jaxpr(F32_MMA_CASES[name])(x)
+    dots = [e for e, _ in rinspect.iter_eqns(jaxpr)
+            if e.primitive.name == "dot_general"]
+    assert dots
+    for e in dots:
+        wide = any(v.aval.dtype == jnp.float32 for v in e.invars)
+        prec = e.params["precision"]
+        highest = prec is not None and all(
+            p == jax.lax.Precision.HIGHEST for p in prec)
+        assert highest == wide, (name, e)

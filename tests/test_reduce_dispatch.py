@@ -703,6 +703,38 @@ def test_autotune_feeds_plan_cache(rng):
         R.plan_cache_clear(clear_tuned=True)
 
 
+def test_autotune_records_a_candidate_that_raises():
+    """A backend that raises loses the race loudly: a warning, and its
+    plan and error stay readable through autotune_failures."""
+
+    class Refused(R.Backend):
+        name = "refused"
+
+        def sum_all(self, x, plan):
+            raise RuntimeError("refused by the compiler")
+
+        def sum_axis(self, x, plan):
+            raise RuntimeError("refused by the compiler")
+
+    from repro.reduce import backends as B
+
+    shape, dt = (4096,), jnp.float32
+    R.plan_cache_clear(clear_tuned=True)
+    try:
+        R.register_backend(Refused())
+        with pytest.warns(UserWarning, match="refused by the compiler"):
+            best = R.autotune(shape, dt, backends=("xla", "refused"),
+                              repeats=1)
+        assert best.backend == "xla"
+        (failed,) = R.autotune_failures(shape, dt)
+        assert failed[0].backend == "refused"
+        assert "refused by the compiler" in failed[1]
+    finally:
+        B._REGISTRY.pop("refused", None)
+        R.plan_cache_clear(clear_tuned=True)
+    assert R.autotune_failures(shape, dt) == ()
+
+
 # ------------------------------ jit + legacy shims ---------------------------
 
 

@@ -8,10 +8,10 @@ import pytest
 def test_train_loss_descends_e2e(tmp_path):
     from repro.launch.train import main
 
-    losses = main([
+    losses = [r["loss"] for r in main([
         "--arch", "olmo-1b", "--tiny", "--steps", "14", "--batch", "4",
         "--seq", "48", "--log-every", "7", "--lr", "3e-3",
-    ])
+    ])]
     assert len(losses) == 14
     assert losses[-1] < losses[0]
     assert all(np.isfinite(l) for l in losses)
@@ -27,8 +27,8 @@ def test_train_resume_e2e(tmp_path):
 
     main(args(8))               # runs 8 steps, ckpt at 4 and 8
     resumed = main(args(10))    # resumes at 8, runs 2 more
-    assert len(resumed) == 2
-    assert all(np.isfinite(l) for l in resumed)
+    assert [r["step"] for r in resumed] == [9, 10]
+    assert all(np.isfinite(r["loss"]) for r in resumed)
 
 
 def test_serve_batched_e2e():

@@ -336,3 +336,32 @@ def test_property_zero_copy_vs_oracle(n, seed, num_cores, dt):
     got = float(R.reduce(x, backend="pallas_fused", num_cores=num_cores))
     tol = (4e-3 if dt == "float32" else 1.6e-2) * max(np.abs(x64).sum(), 1e-3)
     assert abs(got - x64.sum()) <= tol
+
+
+@pytest.mark.parametrize("shape,dt", [
+    ((64, 256), jnp.float32),       # one whole block per step
+    ((70, 256), jnp.float32),       # ragged last block
+    ((40, 2048), jnp.bfloat16),     # two tiles per step, ragged rows
+    ((2, 16, 1024), jnp.bfloat16),  # leading dims collapse into rows
+])
+def test_parts_2d_view_matches_flat_stream_bitwise(shape, dt, rng):
+    """A leaf whose last dim tiles evenly streams through its (rows, C)
+    view (no flat relayout on the chip); its blocks hold the flat stream's
+    tiles in stream order, so every slot -- sum, census, the clip fork --
+    is bit-identical to the same leaf streamed flat."""
+    x = jnp.asarray(rng.randn(*shape).astype(np.float32)).astype(dt)
+    assert K.part_view(x).ndim == 2
+    small = jnp.asarray(rng.randn(33).astype(np.float32))
+
+    def stat(leaves):
+        return ops.mma_sum_parts_pallas(
+            leaves, prologue="square", total_chains=((), ("clip_coeff", 1.0)),
+            census=True,
+        )
+
+    got = stat([x, small])
+    want = stat([x.reshape(-1), small])
+    harness.assert_bits_equal(got, want, str(shape))
+    assert rinspect.pallas_io_bytes(jax.make_jaxpr(stat)([x, small])) == \
+        rinspect.pallas_io_bytes(jax.make_jaxpr(stat)([x.reshape(-1), small]))
+    rinspect.assert_staging_free(stat, [x, small])
