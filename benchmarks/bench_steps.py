@@ -173,7 +173,8 @@ def serving_rows():
             f"of={n_req};shed={snap['shed_queue_full']}"
             f"+{snap['shed_infeasible']};missed={snap['deadline_missed']};"
             f"quarantined={snap['quarantined']};retries={snap['retries']};"
-            f"p99_step_ms={snap['token_latency_p99_s'] * 1e3:.1f}"
+            f"ttft_p99_ms={snap['ttft_p99_s'] * 1e3:.1f};"
+            f"itl_p99_ms={snap['itl_p99_s'] * 1e3:.1f}"
         )
     return out
 

@@ -37,6 +37,7 @@ from repro.launch.device import print_device_report, use_compile_cache
 from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.models import init_params, make_caches
 from repro.models.frontends import synth_image_embeds
+from repro.runtime.metrics import span
 from repro.runtime.serving import (
     Request,
     ServingRuntime,
@@ -49,6 +50,13 @@ def _tok_ints(tok) -> np.ndarray:
     (codebook models report codebook 0, as the plain loop always has)."""
     a = np.asarray(tok)
     return a[:, 0] if a.ndim == 2 else a[:, 0, 0]
+
+
+def _read_back(tok, census):
+    """A step's tokens and census on the host: the step's two waits on the
+    device."""
+    with span("serve.readback"):
+        return _tok_ints(tok), np.asarray(census)
 
 
 class Engine:
@@ -167,7 +175,8 @@ class GuardedEngine(Engine):
         def step(params, prompts, scales, ctx=None):
             logits, caches = prefill(params, prompts, ctx)
             logits = self._scale_logits(logits, scales)
-            stat, census = guarded_logit_stat(logits, backend=backend)
+            with jax.named_scope("census"):
+                stat, census = guarded_logit_stat(logits, backend=backend)
             tok = jnp.argmax(logits, -1).astype(jnp.int32)
             if tok.ndim == 2:
                 tok = tok[:, :1]
@@ -186,7 +195,8 @@ class GuardedEngine(Engine):
         def step(params, caches, tok, pos, scales, ctx=None):
             logits, caches = decode_logits(params, caches, tok, pos, ctx)
             logits = self._scale_logits(logits, scales)
-            stat, census = guarded_logit_stat(logits, backend=backend)
+            with jax.named_scope("census"):
+                stat, census = guarded_logit_stat(logits, backend=backend)
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)
             return nxt, caches, stat, census
 
@@ -200,31 +210,33 @@ class GuardedEngine(Engine):
         live = [p for p in prompts if p is not None]
         if not live:
             raise ValueError("start_wave needs at least one live prompt")
-        packed = self._pack_wave([np.asarray(p) for p in live])
-        # dummy-slot scales are 1.0 (the runtime already sends 1.0 for
-        # masked slots, but the wave list may be SHORTER than slots)
-        s = np.ones((self.slots,), np.float32)
-        s[: len(scales)] = np.asarray(scales, np.float32)[: self.slots]
-        tok, caches, _stat, census = self._prefill_fn(backend)(
-            self.params, packed, jnp.asarray(s),
-            *((self.ctx,) if self.ctx is not None else ()),
-        )
+        with span("serve.dispatch"):
+            packed = self._pack_wave([np.asarray(p) for p in live])
+            # dummy-slot scales are 1.0 (the runtime already sends 1.0 for
+            # masked slots, but the wave list may be SHORTER than slots)
+            s = np.ones((self.slots,), np.float32)
+            s[: len(scales)] = np.asarray(scales, np.float32)[: self.slots]
+            tok, caches, _stat, census = self._prefill_fn(backend)(
+                self.params, packed, jnp.asarray(s),
+                *((self.ctx,) if self.ctx is not None else ()),
+            )
         state = {"caches": caches, "tok": tok, "pos": int(packed.shape[1]),
                  "t": 0}
-        return state, _tok_ints(tok), np.asarray(census)
+        return (state,) + _read_back(tok, census)
 
     def decode(self, state: dict, scales, backend: str):
-        s = np.ones((self.slots,), np.float32)
-        s[: len(scales)] = np.asarray(scales, np.float32)[: self.slots]
-        tok, caches, _stat, census = self._decode_fn(backend)(
-            self.params, state["caches"], state["tok"],
-            jnp.asarray(state["pos"] + state["t"], jnp.int32),
-            jnp.asarray(s),
-            *((self.ctx,) if self.ctx is not None else ()),
-        )
+        with span("serve.dispatch"):
+            s = np.ones((self.slots,), np.float32)
+            s[: len(scales)] = np.asarray(scales, np.float32)[: self.slots]
+            tok, caches, _stat, census = self._decode_fn(backend)(
+                self.params, state["caches"], state["tok"],
+                jnp.asarray(state["pos"] + state["t"], jnp.int32),
+                jnp.asarray(s),
+                *((self.ctx,) if self.ctx is not None else ()),
+            )
         new_state = {"caches": caches, "tok": tok, "pos": state["pos"],
                      "t": state["t"] + 1}
-        return new_state, _tok_ints(tok), np.asarray(census)
+        return (new_state,) + _read_back(tok, census)
 
 
 def main(argv=None):
@@ -302,9 +314,12 @@ def main(argv=None):
         print(f"admitted={snap['admitted']} shed={snap['shed_queue_full']}"
               f"+{snap['shed_infeasible']} deadline_missed="
               f"{snap['deadline_missed']} quarantined={snap['quarantined']} "
-              f"breaker_trips={snap['breaker_trips']} "
-              f"p50={snap['token_latency_p50_s'] * 1e3:.1f}ms "
-              f"p99={snap['token_latency_p99_s'] * 1e3:.1f}ms")
+              f"breaker_trips={snap['breaker_trips']}")
+        print(f"ttft p50={snap['ttft_p50_s'] * 1e3:.1f}ms "
+              f"p99={snap['ttft_p99_s'] * 1e3:.1f}ms  "
+              f"itl p50={snap['itl_p50_s'] * 1e3:.1f}ms "
+              f"p99={snap['itl_p99_s'] * 1e3:.1f}ms  "
+              f"live slots {snap['live_slot_steps']}/{snap['slot_steps']}")
         return results, snap
     eng = Engine(cfg, s_max, args.batch_slots)
     outs = eng.serve(reqs, args.max_new)

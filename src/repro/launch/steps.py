@@ -76,6 +76,9 @@ def _make_grads_fn(cfg, tcfg, mesh, param_shardings, bspec):
     shared by the plain and the guarded train steps:
     ``compute_grads(params, batch) -> (grads, mean_loss)``."""
 
+    # the scope names the forward pass in the compiled program's op names;
+    # its backward is ``transpose(jvp(forward))``
+    @jax.named_scope("forward")
     def loss_fn(params, tokens, ctx):
         h, aux = forward_hidden(params, cfg, tokens[:, :-1], ctx)
         labels = tokens[:, 1:]  # (B, S-1[, K]); chunked CE handles codebooks
@@ -317,8 +320,10 @@ def make_jitted_guarded_train_step(
 
 
 def make_prefill_step(cfg: ModelConfig, s_max: int):
+    @jax.named_scope("model")
     def prefill_step(params, tokens, ctx=None):
-        caches = make_caches(cfg, tokens.shape[0], s_max)
+        with jax.named_scope("kv_cache"):
+            caches = make_caches(cfg, tokens.shape[0], s_max)
         logits, caches = prefill(params, cfg, tokens, caches, ctx)
         return logits, caches
 
@@ -327,7 +332,9 @@ def make_prefill_step(cfg: ModelConfig, s_max: int):
 
 def make_decode_step(cfg: ModelConfig, greedy: bool = True):
     def decode_one(params, caches, token, pos, ctx=None):
-        logits, caches = model_decode(params, cfg, token, caches, pos, ctx)
+        with jax.named_scope("model"):
+            logits, caches = model_decode(params, cfg, token, caches, pos,
+                                          ctx)
         if greedy:
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)
         else:

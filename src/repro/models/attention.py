@@ -235,11 +235,12 @@ def self_attention_decode(p, x_t, cache, pos, cfg, *, window=None):
     # full cache: s_max > pos always so slot == pos; ring cache (local attn,
     # s_max == window): the slot rotates and evicts the oldest key.
     slot = pos % s_max
-    k_cache = jax.lax.dynamic_update_slice(cache["k"], k, (0, slot, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(cache["v"], v, (0, slot, 0, 0))
-    slot_pos = jax.lax.dynamic_update_slice(
-        cache["slot_pos"], pos[None].astype(jnp.int32), (slot,)
-    )
+    with jax.named_scope("kv_cache"):
+        k_cache = jax.lax.dynamic_update_slice(cache["k"], k, (0, slot, 0, 0))
+        v_cache = jax.lax.dynamic_update_slice(cache["v"], v, (0, slot, 0, 0))
+        slot_pos = jax.lax.dynamic_update_slice(
+            cache["slot_pos"], pos[None].astype(jnp.int32), (slot,)
+        )
     out = decode_attention(
         q, k_cache, v_cache, slot_pos, pos, window=window, mma=cfg.mma_reductions
     )
@@ -255,9 +256,10 @@ def fill_kv_cache(p, x, positions, cache, cfg):
     k = L.rope(k, positions, cfg.rope_theta)
     s_max = cache["k"].shape[1]
     if s <= s_max:
-        k_cache = jax.lax.dynamic_update_slice(cache["k"], k, (0, 0, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(cache["v"], v, (0, 0, 0, 0))
-        slot_pos = cache["slot_pos"].at[:s].set(jnp.arange(s))
+        with jax.named_scope("kv_cache"):
+            k_cache = jax.lax.dynamic_update_slice(cache["k"], k, (0, 0, 0, 0))
+            v_cache = jax.lax.dynamic_update_slice(cache["v"], v, (0, 0, 0, 0))
+            slot_pos = cache["slot_pos"].at[:s].set(jnp.arange(s))
     else:  # ring: keep the last s_max positions, each at slot pos % s_max so
         # later decode writes (slot = pos % s_max) evict oldest-first.
         tail = jnp.arange(s - s_max, s)

@@ -365,6 +365,27 @@ def make_caches(cfg: ModelConfig, batch: int, s_max: int):
     return caches
 
 
+@jax.named_scope("kv_cache")
+def _cache_layer(stacked, i):
+    """Layer ``i``'s caches, sliced from the stacked caches."""
+    return jax.tree.map(
+        lambda c: jax.lax.dynamic_index_in_dim(c, i, 0, keepdims=False),
+        stacked,
+    )
+
+
+@jax.named_scope("kv_cache")
+def _cache_set_layer(stacked, new_cache, i):
+    """The stacked caches with layer ``i``'s replaced by ``new_cache``."""
+    return jax.tree.map(
+        lambda c, nc: jax.lax.dynamic_update_index_in_dim(
+            c, nc.astype(c.dtype), i, 0
+        ),
+        stacked,
+        new_cache,
+    )
+
+
 def prefill(params, cfg: ModelConfig, tokens, caches, ctx=None):
     """Run the prompt, filling caches. Returns (last-token logits, caches)."""
     pat, n_units, tail = _pattern_units(cfg)
@@ -375,10 +396,7 @@ def prefill(params, cfg: ModelConfig, tokens, caches, ctx=None):
     def unit_fn(carry, xs):
         hh, aux, stacked = carry
         unit_params, i = xs
-        unit_cache = jax.tree.map(
-            lambda c: jax.lax.dynamic_index_in_dim(c, i, 0, keepdims=False),
-            stacked,
-        )
+        unit_cache = _cache_layer(stacked, i)
         new_cache = {}
         for j, kind in enumerate(pat):
             hh, a, new_cache[f"pos{j}"] = block_fill_cache(
@@ -387,13 +405,7 @@ def prefill(params, cfg: ModelConfig, tokens, caches, ctx=None):
             )
             hh = CTX.constrain(hh)
             aux = aux + a
-        stacked = jax.tree.map(
-            lambda c, nc: jax.lax.dynamic_update_index_in_dim(
-                c, nc.astype(c.dtype), i, 0
-            ),
-            stacked,
-            new_cache,
-        )
+        stacked = _cache_set_layer(stacked, new_cache, i)
         return (hh, aux, stacked), None
 
     (h, _, new_units), _ = jax.lax.scan(
@@ -427,23 +439,14 @@ def decode_step(params, cfg: ModelConfig, token_t, caches, pos, ctx=None):
     def unit_fn(carry, xs):
         hh, stacked = carry
         unit_params, i = xs
-        unit_cache = jax.tree.map(
-            lambda c: jax.lax.dynamic_index_in_dim(c, i, 0, keepdims=False),
-            stacked,
-        )
+        unit_cache = _cache_layer(stacked, i)
         new_cache = {}
         for j, kind in enumerate(pat):
             hh, new_cache[f"pos{j}"] = block_decode(
                 kind, unit_params[f"pos{j}"], hh, unit_cache[f"pos{j}"], pos, cfg, ctx
             )
             hh = CTX.constrain(hh)
-        stacked = jax.tree.map(
-            lambda c, nc: jax.lax.dynamic_update_index_in_dim(
-                c, nc.astype(c.dtype), i, 0
-            ),
-            stacked,
-            new_cache,
-        )
+        stacked = _cache_set_layer(stacked, new_cache, i)
         return (hh, stacked), None
 
     (h, new_units), _ = jax.lax.scan(

@@ -216,6 +216,7 @@ def _adamw_core(
     return new_p, AdamWState(step=step, m=new_m, v=new_v), lr
 
 
+@jax.named_scope("optimizer")
 def apply_updates(
     params,
     grads,
@@ -332,6 +333,7 @@ def _loss_spike(guard: GuardState, loss, spike_z: float):
     return full & _finite_scalar(loss) & ((loss - med) > spike_z * scale)
 
 
+@jax.named_scope("optimizer")
 def guarded_apply_updates(
     params,
     grads,

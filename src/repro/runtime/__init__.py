@@ -26,6 +26,7 @@ from repro.runtime.serving import (  # noqa: F401
     Completion,
     DeadlineExceeded,
     Request,
+    RequestRecord,
     RequestRejected,
     ServingRuntime,
     guarded_logit_stat,

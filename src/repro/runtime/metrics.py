@@ -1,4 +1,5 @@
-"""Guard observability: counters for the skip/retry/rollback machinery.
+"""Guard observability: counters for the skip/retry/rollback machinery,
+and host spans on the profiler's clock.
 
 A guarded run that silently skips 30% of its steps is a broken run that
 LOOKS healthy; these counters make the guard's behavior visible. The
@@ -7,15 +8,36 @@ every checkpoint commit, and ``write()`` exports an atomic JSON status
 file that an external watchdog (or the next incarnation after a restart)
 can poll without touching the training process.
 
+``span(name, **args)`` opens a host span of the profiler
+(``jax.profiler.TraceAnnotation``): when a trace is recorded it lands on
+the same clock and in the same file as the device operations, so an idle
+gap of the device can be put down to what the host was doing; when none
+is, entering and leaving one costs about a microsecond.
+
 Plain Python, no jax at module import -- callers pass already-materialized
-floats/ints (the supervisor reads them off the step's metrics dict).
+floats/ints (the supervisor reads them off the step's metrics dict), and
+the profiler is imported at the first span.
 """
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import tempfile
+
+_annotation = None
+
+
+def span(name: str, **args):
+    """A profiler host span, as a context manager; ``args`` are recorded
+    with it (ints or strings)."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation(name, **args)
 
 
 class GuardMetrics:
@@ -96,12 +118,14 @@ class ServeMetrics(GuardMetrics):
 
     Admission (admitted/shed_queue_full/shed_infeasible), deadline misses,
     per-slot quarantines, breaker trips + live per-backend breaker states,
-    completed requests/tokens, and a bounded reservoir of per-token decode
-    latencies summarized as p50/p99 in the snapshot. Everything exports
+    completed requests/tokens, slot-steps run and slot-steps that received
+    a token (``slot_steps``/``live_slot_steps``), and the time to first
+    token and gaps between tokens of the newest ``request_window``
+    requests, summarized as p50/p99 in the snapshot. Everything exports
     through the same atomic-JSON ``write()`` (``--status-path``) the
     training supervisor uses, so one watchdog polls both shapes."""
 
-    def __init__(self, latency_window: int = 4096):
+    def __init__(self, request_window: int = 1024):
         super().__init__()
         self.admitted = 0
         self.shed_queue_full = 0
@@ -112,9 +136,12 @@ class ServeMetrics(GuardMetrics):
         self.breaker_trips = 0
         self.completed = 0
         self.tokens_out = 0
+        self.slot_steps = 0
+        self.live_slot_steps = 0
         self.breaker_states: dict = {}
-        self._latency_window = int(latency_window)
-        self._latencies: list = []
+        # (ttft, [gaps]) of the newest requests: a long-running server's
+        # tail stays current, not lifetime-averaged
+        self._requests = collections.deque(maxlen=int(request_window))
 
     def record_admit(self) -> None:
         self.admitted += 1
@@ -145,16 +172,20 @@ class ServeMetrics(GuardMetrics):
         self.completed += 1
         self.tokens_out += int(n_tokens)
 
-    def record_token_latency(self, seconds: float) -> None:
-        """One decode step's wall time (one token per active slot). The
-        reservoir keeps the newest ``latency_window`` samples -- a long-
-        running server's tail stays current, not lifetime-averaged."""
-        self._latencies.append(float(seconds))
-        if len(self._latencies) > self._latency_window:
-            del self._latencies[: len(self._latencies) - self._latency_window]
+    def record_engine_step(self, slots: int, live: int) -> None:
+        """One committed engine step: its ``slots`` and the ``live`` ones
+        among them that received a token."""
+        self.slot_steps += int(slots)
+        self.live_slot_steps += int(live)
+
+    def record_request(self, ttft_s: float, gaps_s) -> None:
+        """A finished request that received a token: its time from
+        submission to the first token, and the gaps between its tokens."""
+        self._requests.append((float(ttft_s), [float(g) for g in gaps_s]))
 
     def snapshot(self) -> dict:
-        lat = sorted(self._latencies)
+        ttft = sorted(t for t, _ in self._requests)
+        itl = sorted(g for _, gaps in self._requests for g in gaps)
         snap = super().snapshot()
         snap.update(
             {
@@ -168,9 +199,13 @@ class ServeMetrics(GuardMetrics):
                 "breaker_states": self.breaker_states,
                 "completed": self.completed,
                 "tokens_out": self.tokens_out,
-                "token_latency_p50_s": _percentile(lat, 0.50),
-                "token_latency_p99_s": _percentile(lat, 0.99),
-                "token_latency_samples": len(lat),
+                "slot_steps": self.slot_steps,
+                "live_slot_steps": self.live_slot_steps,
+                "ttft_p50_s": _percentile(ttft, 0.50),
+                "ttft_p99_s": _percentile(ttft, 0.99),
+                "itl_p50_s": _percentile(itl, 0.50),
+                "itl_p99_s": _percentile(itl, 0.99),
+                "latency_requests": len(ttft),
             }
         )
         return snap

@@ -29,10 +29,22 @@ the same primitives:
                     backend in the PLANNER (``reduce.quarantine_backend``)
                     so auto-selected plans elsewhere in the process cannot
                     resurrect it; half-open probes address it explicitly.
-  observability  -- ``ServeMetrics`` (admitted/shed/deadline-missed/
-                    quarantined/breaker state, p50/p99 per-token latency)
-                    exported through the atomic-JSON ``--status-path``
-                    mechanism shared with the training supervisor.
+  observability  -- ``requests()`` gives every request's record on the
+                    runtime's clock: submitted, launched (its wave's
+                    prefill launch), first token, finished, each token's
+                    time and the outcome. ``ServeMetrics`` counts
+                    admitted/shed/deadline-missed/quarantined/breaker
+                    state, slot-steps run and those that received a token,
+                    and summarizes the newest requests' time to first
+                    token and gaps between tokens as p50/p99; it exports
+                    through the atomic-JSON ``--status-path`` mechanism
+                    shared with the training supervisor. Host spans of the
+                    profiler (``metrics.span``) mark ``serve.admit``
+                    (``submit``), ``serve.wave`` (one wave, with its index
+                    and live slots), and ``serve.prefill`` / ``serve.step``
+                    (each attempt of a wave's prefill or decode step);
+                    ``launch.serve.GuardedEngine`` marks ``serve.dispatch``
+                    and ``serve.readback`` inside them.
 
 The runtime is ENGINE-AGNOSTIC: anything with the three-method protocol
 below serves (``launch.serve.GuardedEngine`` adapts the real model; tests
@@ -65,7 +77,7 @@ import time
 from typing import Callable, Optional, Sequence
 
 from repro.runtime.chaos import ChaosMonkey, Preemption, TransientFault
-from repro.runtime.metrics import ServeMetrics
+from repro.runtime.metrics import ServeMetrics, span
 
 # The default degradation order: the kernel backend first, the pure-JAX
 # MMA emulation behind it, the always-available XLA fallback terminal.
@@ -118,6 +130,37 @@ class DeadlineExceeded:
     @property
     def ok(self) -> bool:
         return False
+
+
+@dataclasses.dataclass(frozen=True)
+class RequestRecord:
+    """One request's times on the runtime's clock, and its outcome.
+
+    ``launched`` is its wave's prefill launch and ``first_token`` /
+    ``token_times`` when each of its tokens came back (None / empty if it
+    never reached a wave); ``finished`` is when its outcome was decided,
+    and ``outcome`` is None while it is still queued or in service."""
+
+    rid: int
+    submitted: float
+    launched: Optional[float]
+    first_token: Optional[float]
+    finished: Optional[float]
+    token_times: tuple
+    outcome: object  # Completion | RequestRejected | DeadlineExceeded
+
+
+class _Times:
+    """A request's record while it is served: its token times are indices
+    into the runtime's list of step times, one clock read per step."""
+
+    __slots__ = ("submitted", "launched", "steps", "finished")
+
+    def __init__(self, submitted: float):
+        self.submitted = submitted
+        self.launched = None
+        self.steps: list = []
+        self.finished = None
 
 
 class AdmissionQueue:
@@ -369,6 +412,9 @@ class ServingRuntime:
         # has been measured (feasibility refusals need real evidence).
         self._step_ewma: Optional[float] = None
         self._results: dict = {}
+        self._times: dict = {}     # rid -> _Times
+        self._step_t: list = []    # clock read at the end of every step
+        self._waves = 0
 
     # -- admission ---------------------------------------------------------
 
@@ -382,13 +428,18 @@ class ServingRuntime:
     def submit(self, req: Request) -> bool:
         """Admit ``req`` or record a structured refusal. Returns True iff
         admitted (the result then arrives via ``serve``'s drain)."""
+        with span("serve.admit"):
+            return self._admit(req)
+
+    def _admit(self, req: Request) -> bool:
         now = self.clock()
+        self._times[req.rid] = _Times(now)
         err = None
         validate = getattr(self.engine, "validate", None)
         if validate is not None:
             err = validate(req.prompt, req.max_new)
         if err:
-            self._results[req.rid] = RequestRejected(req.rid, err)
+            self._outcome(RequestRejected(req.rid, err), now)
             self.metrics.record_shed(infeasible=True)
             return False
         if req.deadline_s is not None:
@@ -396,21 +447,21 @@ class ServingRuntime:
             if now > req.deadline_s or (
                 est is not None and now + est > req.deadline_s
             ):
-                self._results[req.rid] = RequestRejected(
+                self._outcome(RequestRejected(
                     req.rid,
                     "infeasible: deadline cannot be met "
                     f"(estimated {est if est is not None else 0.0:.4f}s)",
-                )
+                ), now)
                 self.metrics.record_shed(infeasible=True)
                 return False
         admitted, shed = self.queue.submit(req, now)
         for victim in shed:
-            self._results[victim.rid] = DeadlineExceeded(victim.rid)
+            self._outcome(DeadlineExceeded(victim.rid), now)
             self.metrics.record_deadline_miss()
         if not admitted:
-            self._results[req.rid] = RequestRejected(
+            self._outcome(RequestRejected(
                 req.rid, f"queue full (capacity {self.queue.capacity})"
-            )
+            ), now)
             self.metrics.record_shed()
             return False
         self.metrics.record_admit()
@@ -433,41 +484,44 @@ class ServingRuntime:
                 scales.append(self.chaos.scale_for(slot.rid))
         return scales
 
-    def _guarded_call(self, wave, live, call):
+    def _guarded_call(self, wave, live, call, name: str):
         """Run one engine step until its census is clean for every live
         slot (or retries run out). ``call(scales, backend)`` issues the
-        step from the COMMITTED state. Returns (state, tokens, poisoned):
-        ``poisoned`` is the set of slot indices still non-finite on the
-        final attempt (their state never commits -- they are dead)."""
+        step from the COMMITTED state; each attempt is a host span
+        ``name``. Returns (state, tokens, poisoned): ``poisoned`` is the
+        set of slot indices still non-finite on the final attempt (their
+        state never commits -- they are dead)."""
         last_poisoned: set = set()
         for attempt in range(self.max_step_retries + 1):
-            backend = self.breaker.backend()
-            try:
-                self._chaos_precheck(
-                    wave[i].rid for i in sorted(live)
-                )
-                scales = self._scales(
-                    [wave[i] if i in live else None for i in range(len(wave))]
-                )
-                state, tokens, census = call(scales, backend)
-            except Preemption:
+            with span(name):
+                backend = self.breaker.backend()
+                try:
+                    self._chaos_precheck(
+                        wave[i].rid for i in sorted(live)
+                    )
+                    scales = self._scales(
+                        [wave[i] if i in live else None
+                         for i in range(len(wave))]
+                    )
+                    state, tokens, census = call(scales, backend)
+                except Preemption:
+                    self.metrics.record_retry()
+                    continue
+                except TransientFault:
+                    self.breaker.record_failure(backend)
+                    self.metrics.record_retry()
+                    continue
+                poisoned = {
+                    i for i in live if float(census[i]) > 0.0
+                }
+                if not poisoned:
+                    self.breaker.record_success(backend)
+                    return state, tokens, set()
+                self.metrics.record_quarantine(len(poisoned))
                 self.metrics.record_retry()
-                continue
-            except TransientFault:
-                self.breaker.record_failure(backend)
-                self.metrics.record_retry()
-                continue
-            poisoned = {
-                i for i in live if float(census[i]) > 0.0
-            }
-            if not poisoned:
-                self.breaker.record_success(backend)
-                return state, tokens, set()
-            self.metrics.record_quarantine(len(poisoned))
-            self.metrics.record_retry()
-            last_poisoned = poisoned
-            if attempt == self.max_step_retries:
-                return state, tokens, poisoned
+                last_poisoned = poisoned
+                if attempt == self.max_step_retries:
+                    return state, tokens, poisoned
         # every attempt raised: surface the persistent fault
         raise TransientFault(
             f"step failed after {self.max_step_retries + 1} attempts "
@@ -476,9 +530,46 @@ class ServingRuntime:
 
     # -- the wave loop -----------------------------------------------------
 
+    def _outcome(self, result, now: float) -> None:
+        """Decide a request's outcome at ``now``; a request that received
+        tokens enters the metrics' latency window."""
+        self._results[result.rid] = result
+        times = self._times[result.rid]
+        times.finished = now
+        if times.steps:
+            t = [self._step_t[k] for k in times.steps]
+            self.metrics.record_request(
+                t[0] - times.submitted, [b - a for a, b in zip(t, t[1:])]
+            )
+
     def _finish(self, req: Request, tokens: list) -> None:
-        self._results[req.rid] = Completion(req.rid, tuple(tokens))
+        # finished when its last token came back: no clock read per slot
+        steps = self._times[req.rid].steps
+        last = self._step_t[steps[-1]] if steps else self.clock()
+        self._outcome(Completion(req.rid, tuple(tokens)), last)
         self.metrics.record_completed(len(tokens))
+
+    def _commit_step(self, now: float, got_token) -> None:
+        """One committed engine step that ended at ``now``: the requests of
+        the slots in ``got_token`` received a token then."""
+        k = len(self._step_t)
+        self._step_t.append(now)
+        for req in got_token:
+            self._times[req.rid].steps.append(k)
+        self.metrics.record_engine_step(self.engine.slots, len(got_token))
+
+    def requests(self) -> dict:
+        """``{rid: RequestRecord}`` for every request submitted, in the
+        order of submission."""
+        out = {}
+        for rid, tm in self._times.items():
+            tt = tuple(self._step_t[k] for k in tm.steps)
+            out[rid] = RequestRecord(
+                rid=rid, submitted=tm.submitted, launched=tm.launched,
+                first_token=tt[0] if tt else None, finished=tm.finished,
+                token_times=tt, outcome=self._results.get(rid),
+            )
+        return out
 
     def _run_wave(self, wave_reqs) -> None:
         slots = self.engine.slots
@@ -491,21 +582,20 @@ class ServingRuntime:
             for i in sorted(live):
                 r = wave[i]
                 if r.deadline_s is not None and now > r.deadline_s:
-                    self._results[r.rid] = DeadlineExceeded(
-                        r.rid, tuple(toks[i])
-                    )
+                    self._outcome(DeadlineExceeded(r.rid, tuple(toks[i])),
+                                  now)
                     self.metrics.record_deadline_miss()
                     live.discard(i)
 
-        def kill_poisoned(poisoned) -> None:
+        def kill_poisoned(poisoned, now: float) -> None:
             for i in sorted(poisoned):
                 r = wave[i]
-                self._results[r.rid] = RequestRejected(
+                self._outcome(RequestRejected(
                     r.rid,
                     "poisoned: non-finite logits persisted across "
                     f"{self.max_step_retries + 1} attempts",
                     tuple(toks[i]),
-                )
+                ), now)
                 self.metrics.record_poisoned()
                 live.discard(i)
 
@@ -514,16 +604,20 @@ class ServingRuntime:
         expire(t0)
         if not live:
             return
+        for i in live:
+            self._times[wave[i].rid].launched = t0
         state, tokens, poisoned = self._guarded_call(
             wave, live, lambda scales, backend: self.engine.start_wave(
                 prompts, scales, backend
-            )
+            ), "serve.prefill"
         )
-        self._record_step_time(self.clock() - t0)
-        kill_poisoned(poisoned)
-        for i in live:
-            if len(toks[i]) < wave[i].max_new:
-                toks[i].append(int(tokens[i]))
+        t_end = self.clock()
+        self._record_step_time(t_end - t0)
+        kill_poisoned(poisoned, t_end)
+        got = [i for i in sorted(live) if len(toks[i]) < wave[i].max_new]
+        for i in got:
+            toks[i].append(int(tokens[i]))
+        self._commit_step(t_end, [wave[i] for i in got])
         for t in range(1, max_new):
             done = {i for i in live if len(toks[i]) >= wave[i].max_new}
             for i in sorted(done):
@@ -536,18 +630,19 @@ class ServingRuntime:
             new_state, tokens, poisoned = self._guarded_call(
                 wave, live, lambda scales, backend: self.engine.decode(
                     state, scales, backend
-                )
+                ), "serve.step"
             )
-            self._record_step_time(self.clock() - t1)
+            t_end = self.clock()
+            self._record_step_time(t_end - t1)
             state = new_state
-            kill_poisoned(poisoned)
+            kill_poisoned(poisoned, t_end)
             for i in live:
                 toks[i].append(int(tokens[i]))
+            self._commit_step(t_end, [wave[i] for i in sorted(live)])
         for i in sorted(live):
             self._finish(wave[i], toks[i])
 
     def _record_step_time(self, dt: float) -> None:
-        self.metrics.record_token_latency(dt)
         if self._step_ewma is None:
             self._step_ewma = dt
         else:
@@ -573,11 +668,14 @@ class ServingRuntime:
     def drain(self) -> None:
         """Run queued waves to completion, exporting status every wave."""
         while len(self.queue):
-            wave, expired = self.queue.pop(self.engine.slots, self.clock())
+            now = self.clock()
+            wave, expired = self.queue.pop(self.engine.slots, now)
             for r in expired:
-                self._results[r.rid] = DeadlineExceeded(r.rid)
+                self._outcome(DeadlineExceeded(r.rid), now)
                 self.metrics.record_deadline_miss()
             if wave:
-                self._run_wave(wave)
+                with span("serve.wave", wave=self._waves, live=len(wave)):
+                    self._run_wave(wave)
+                self._waves += 1
             self._export()
         self._export()

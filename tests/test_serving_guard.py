@@ -418,8 +418,9 @@ def test_status_json_counters_match_injection_schedule(tmp_path):
     assert snap["breaker_trips"] == 1
     assert snap["breaker_states"]["fakeA"] == "open"
     assert snap["deadline_missed"] == 0
-    assert snap["token_latency_samples"] > 0
-    assert snap["token_latency_p99_s"] >= snap["token_latency_p50_s"] > 0
+    assert snap["latency_requests"] == 6
+    assert snap["ttft_p99_s"] >= snap["ttft_p50_s"] > 0
+    assert snap["itl_p99_s"] >= snap["itl_p50_s"] > 0
 
 
 # ------------------- planner quarantine (breaker re-route) -----------------
