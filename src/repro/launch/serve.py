@@ -4,6 +4,12 @@ Continuous-batching-lite: a request queue is packed into fixed slots; each
 engine step decodes one token for every active slot; finished slots are
 refilled from the queue (prefill) without stopping the decode stream.
 
+The caches stay resident: each decode step DONATES them, and writes only
+position ``pos`` of every position-indexed cache (full K/V, ring K/V, MLA
+latent) in place -- no copy of a cache exists at any point of the step
+(``models.model.decode_step``). Recurrent states (SSM, RG-LRU) are
+O(B * state) and replaced whole; cross-attention caches are only read.
+
 Two paths share the jitted steps:
 
   Engine.serve        -- the plain happy-path loop (padded last wave uses a
@@ -36,6 +42,7 @@ from repro.configs import get_arch
 from repro.launch.device import print_device_report, use_compile_cache
 from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.models import init_params, make_caches
+from repro.models.model import clear_position, merge_caches, split_caches
 from repro.models.frontends import synth_image_embeds
 from repro.runtime.metrics import span
 from repro.runtime.serving import (
@@ -70,7 +77,7 @@ class Engine:
         # underscored: GuardedEngine exposes protocol methods named
         # start_wave/decode, which plain attributes here would shadow
         self._jit_prefill = jax.jit(make_prefill_step(cfg, s_max))
-        self._jit_decode = jax.jit(make_decode_step(cfg))
+        self._jit_decode = jax.jit(make_decode_step(cfg), donate_argnums=(1,))
         self.ctx = (
             synth_image_embeds(
                 jax.random.PRNGKey(1), batch_slots, cfg.n_img_tokens,
@@ -144,16 +151,34 @@ class GuardedEngine(Engine):
     logit statistic with its in-launch non-finite census
     (``guarded_logit_stat`` -- one pallas_call on the kernel backends,
     zero input bytes beyond the logits the statistic already reads) + the
-    greedy argmax. Steps are FUNCTIONAL: caches go in and come out, so
-    the runtime can retry a step from committed state. Keying the jitted
-    functions by backend NAME (not the process default) is what makes the
-    breaker's re-route safe under jit -- a traced computation has its
-    plan baked in, so each backend gets its own trace."""
+    greedy argmax. Keying the jitted functions by backend NAME (not the
+    process default) is what makes the breaker's re-route safe under jit
+    -- a traced computation has its plan baked in, so each backend gets
+    its own trace.
+
+    A decode step donates the wave's position-indexed caches and writes
+    position ``pos`` of them in place; ``decode`` moves the written
+    buffers into the state it was given, which is the runtime's committed
+    state, and into the state it returns. A retry from the committed state
+    therefore runs on caches that already hold position ``pos`` from the
+    failed attempt. It has the same token and position, and a decode step
+    attends no position at or past ``pos`` from the cache; ``decode`` first
+    zeroes that position (``models.model.clear_position``, only on a
+    retry), since the dots still multiply what they weigh 0 and the failed
+    attempt may have written NaN there. So the retry reproduces a clean
+    step bitwise without a second copy of the caches. Recurrent
+    states are not donated: the committed state keeps the ones the step
+    started from, and the step returns new ones. Prefill makes its caches
+    inside the step. ``decode_in_place`` counts the decode calls whose
+    donated caches were consumed (deleted after the call); a backend that
+    declined the donation shows at once as a count below the calls."""
 
     def __init__(self, cfg, s_max: int, batch_slots: int, seed: int = 0):
         super().__init__(cfg, s_max, batch_slots, seed)
         self._guarded_prefill = {}
         self._guarded_decode = {}
+        self._clear = jax.jit(clear_position, donate_argnums=(0,))
+        self.decode_in_place = 0
 
     def validate(self, prompt, max_new: int):
         try:
@@ -192,15 +217,16 @@ class GuardedEngine(Engine):
             return fn
         decode_logits = make_decode_step(self.cfg, greedy=False)
 
-        def step(params, caches, tok, pos, scales, ctx=None):
-            logits, caches = decode_logits(params, caches, tok, pos, ctx)
+        def step(params, indexed, rest, tok, pos, scales, ctx=None):
+            logits, caches = decode_logits(
+                params, merge_caches(indexed, rest), tok, pos, ctx)
             logits = self._scale_logits(logits, scales)
             with jax.named_scope("census"):
                 stat, census = guarded_logit_stat(logits, backend=backend)
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            return nxt, caches, stat, census
+            return (nxt,) + split_caches(caches) + (stat, census)
 
-        fn = jax.jit(step)
+        fn = jax.jit(step, donate_argnums=(1,))
         self._guarded_decode[backend] = fn
         return fn
 
@@ -225,17 +251,25 @@ class GuardedEngine(Engine):
         return (state,) + _read_back(tok, census)
 
     def decode(self, state: dict, scales, backend: str):
+        indexed, rest = split_caches(state["caches"])
         with span("serve.dispatch"):
+            pos = jnp.asarray(state["pos"] + state["t"], jnp.int32)
+            if state.get("attempted"):
+                indexed = self._clear(indexed, pos)
             s = np.ones((self.slots,), np.float32)
             s[: len(scales)] = np.asarray(scales, np.float32)[: self.slots]
-            tok, caches, _stat, census = self._decode_fn(backend)(
-                self.params, state["caches"], state["tok"],
-                jnp.asarray(state["pos"] + state["t"], jnp.int32),
-                jnp.asarray(s),
+            tok, indexed_new, rest_new, _stat, census = self._decode_fn(backend)(
+                self.params, indexed, rest, state["tok"], pos, jnp.asarray(s),
                 *((self.ctx,) if self.ctx is not None else ()),
             )
-        new_state = {"caches": caches, "tok": tok, "pos": state["pos"],
-                     "t": state["t"] + 1}
+        self.decode_in_place += all(
+            x.is_deleted() for x in jax.tree.leaves(indexed))
+        # the donated buffers are gone: the committed state now holds the
+        # written ones (see the class docstring for why a retry stays exact)
+        state["caches"] = merge_caches(indexed_new, rest)
+        state["attempted"] = True
+        new_state = {"caches": merge_caches(indexed_new, rest_new), "tok": tok,
+                     "pos": state["pos"], "t": state["t"] + 1}
         return (new_state,) + _read_back(tok, census)
 
 
@@ -319,7 +353,8 @@ def main(argv=None):
               f"p99={snap['ttft_p99_s'] * 1e3:.1f}ms  "
               f"itl p50={snap['itl_p50_s'] * 1e3:.1f}ms "
               f"p99={snap['itl_p99_s'] * 1e3:.1f}ms  "
-              f"live slots {snap['live_slot_steps']}/{snap['slot_steps']}")
+              f"live slots {snap['live_slot_steps']}/{snap['slot_steps']}  "
+              f"decode in place {snap['decode_in_place']}")
         return results, snap
     eng = Engine(cfg, s_max, args.batch_slots)
     outs = eng.serve(reqs, args.max_new)

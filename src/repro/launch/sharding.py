@@ -141,7 +141,7 @@ def batch_spec(mesh: Mesh, extra: tuple = ()) -> P:
 def cache_shardings(caches_shape, cfg, mesh: Mesh):
     """PartitionSpec tree for decode caches, keyed on leaf names.
 
-    k/v:   (B, S, Hkv, D)   -> (batch, None, model*, None)
+    k/v:   (B, Hkv, S, D)   -> (batch, model*, None, None)
     ckv:   (B, S, R)        -> (batch, None, None)      [MLA latent]
     conv:  (B, K-1, C)      -> (batch, None, model)
     state: (B, H, P, N)     -> (batch, model, None, None)  [SSD]
@@ -179,10 +179,10 @@ def cache_shardings(caches_shape, cfg, mesh: Mesh):
             # Never shard d_head -- contracting a sharded minor dim makes
             # GSPMD replicate the cache in f32 (dry-run: 12.9 GB on musicgen
             # decode_32k; see EXPERIMENTS.md Perf iteration 3).
-            if mdl(2):
-                s = P(bspec, None, "model", None)
+            if mdl(1):
+                s = P(bspec, "model", None, None)
             else:
-                s = P(bspec, mdl(1), None, None)
+                s = P(bspec, None, mdl(2), None)
         elif name == "ckv":
             # MLA latent: split-KV over sequence (attention contracts s)
             s = P(bspec, mdl(1), None)

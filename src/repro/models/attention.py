@@ -8,7 +8,9 @@ Three execution paths, one contract (oracle: kernels.flash_attention.ref):
   * TPU kernel: cfg.use_pallas routes to kernels.flash_attention (Pallas).
   * decode: cache-resident single-token attention; full cache for global
     attention, *ring buffer* cache for local (windowed) attention so
-    long_500k holds O(window) state, not O(S).
+    long_500k holds O(window) state, not O(S). The cache is read where it
+    lies, as (B, Hkv, S, D); the token's own key/value join it in the
+    softmax, and the caller writes them at one position.
 
 Softmax denominators ride the MXU via `layers.softmax_mma` / the MMA row-sum
 inside the online update (the paper's eq. 9) when cfg.mma_reductions is on.
@@ -157,40 +159,66 @@ def flash_attention_xla(
 
 def decode_attention(
     q: jax.Array,        # (B, 1, H, D) -- already RoPE'd
-    k_cache: jax.Array,  # (B, Smax, Hkv, D) -- RoPE'd at write time
+    k_cache: jax.Array,  # (B, Hkv, Smax, D) -- RoPE'd at write time
     v_cache: jax.Array,
-    slot_pos: jax.Array,  # (Smax,) int32 absolute position per slot, -1 empty
-    pos: jax.Array,       # scalar: current query position
+    valid: jax.Array,    # (Smax,) bool: the cache positions to attend
+    k_new: jax.Array | None = None,  # (B, Hkv, 1, D): the query's own key
+    v_new: jax.Array | None = None,
     *,
-    window: int | None = None,
     mma: bool = True,
     sm_scale: float | None = None,
 ) -> jax.Array:
+    """Single-token attention over a cache read where it lies.
+
+    The current token's own key and value (``k_new``/``v_new``), not yet in
+    the cache, join the cache's valid positions in one softmax: one max,
+    one sum, the same math as attending over a cache that already held
+    them."""
     b, _, h, d = q.shape
-    hkv = k_cache.shape[2]
+    hkv = k_cache.shape[1]
     g = h // hkv
     scale = sm_scale if sm_scale is not None else d**-0.5
-    qg = q.reshape(b, hkv, g, d)
+    qg = q.reshape(b, hkv, g, d).astype(jnp.bfloat16)
     s = jnp.einsum(
-        "bhgd,bshd->bhgs",
-        qg.astype(jnp.bfloat16),
-        k_cache.astype(jnp.bfloat16),
+        "bhgd,bhsd->bhgs", qg, k_cache.astype(jnp.bfloat16),
         preferred_element_type=jnp.float32,
     ) * scale
-    valid = (slot_pos >= 0) & (slot_pos <= pos)
-    if window is not None:
-        valid &= (pos - slot_pos) < window
     s = jnp.where(valid[None, None, None], s, NEG)
     m = jnp.max(s, -1, keepdims=True)
+    if k_new is not None:
+        s_new = jnp.einsum(
+            "bhgd,bhsd->bhgs", qg, k_new.astype(jnp.bfloat16),
+            preferred_element_type=jnp.float32,
+        ) * scale
+        m = jnp.maximum(m, s_new)
     e = jnp.where(valid[None, None, None], jnp.exp(s - m), 0.0)
     denom = R.reduce(e, axis=-1, backend=R.backend_for_flags(mma))
     out = jnp.einsum(
-        "bhgs,bshd->bhgd",
-        e.astype(jnp.bfloat16),
-        v_cache.astype(jnp.bfloat16),
-        preferred_element_type=jnp.float32,
-    ) / jnp.maximum(denom, 1e-30)[..., None]
+        "bhgs,bhsd->bhgd", e.astype(jnp.bfloat16),
+        v_cache.astype(jnp.bfloat16), preferred_element_type=jnp.float32,
+    )
+    if k_new is not None:
+        e_new = jnp.exp(s_new - m)
+        denom = denom + e_new[..., 0]
+        out = out + jnp.einsum(
+            "bhgs,bhsd->bhgd", e_new.astype(jnp.bfloat16),
+            v_new.astype(jnp.bfloat16), preferred_element_type=jnp.float32,
+        )
+    out = out / jnp.maximum(denom, 1e-30)[..., None]
     return out.reshape(b, 1, h, v_cache.shape[-1]).astype(q.dtype)
+
+
+def decode_valid(slot_pos: jax.Array, pos: jax.Array, window: int | None = None):
+    """The cache positions a decode query at ``pos`` attends besides its
+    own: those written for an earlier position (``slot_pos`` is -1 where
+    nothing was), and inside the window for local attention. Position
+    ``pos`` itself is left out -- its key and value join from outside the
+    cache -- so a cache that already holds it (a retried step's) is
+    weighed as one that does not."""
+    valid = (slot_pos >= 0) & (slot_pos < pos)
+    if window is not None:
+        valid &= (pos - slot_pos) < window
+    return valid
 
 
 # --------------------------- full attention blocks ---------------------------
@@ -215,37 +243,44 @@ def self_attention_train(p, x, positions, cfg, *, window=None):
     return P.dense_apply(p["o"], out.reshape(b, s, -1))
 
 
+def cache_positions(s_max: int) -> int:
+    """Positions a non-ring cache holds for a longest context of ``s_max``:
+    rounded up to a multiple of 16, so the (positions, width) planes fill
+    the chip's bf16 tiles of 16 rows with no padding. Otherwise the TPU
+    compiler lays K/V out with the heads as the tiled rows, and every
+    layer's slice is transposed for the attention dots. Positions past the
+    last written stay empty (``slot_pos`` -1) and are never attended."""
+    return -(-s_max // 16) * 16
+
+
 def make_kv_cache(batch: int, s_max: int, n_kv: int, d_head: int, dtype):
+    """Keys and values as (B, Hkv, Smax, D): each head's positions lie
+    contiguous, as the decode dots read them."""
     return {
-        "k": jnp.zeros((batch, s_max, n_kv, d_head), dtype),
-        "v": jnp.zeros((batch, s_max, n_kv, d_head), dtype),
+        "k": jnp.zeros((batch, n_kv, s_max, d_head), dtype),
+        "v": jnp.zeros((batch, n_kv, s_max, d_head), dtype),
         "slot_pos": jnp.full((s_max,), -1, jnp.int32),
     }
 
 
 def self_attention_decode(p, x_t, cache, pos, cfg, *, window=None):
-    """One decode step. x_t: (B, 1, d); cache: full or ring (ring iff window).
-    Returns (out (B,1,d), new_cache)."""
+    """One decode step. x_t: (B, 1, d); cache: full or ring (ring iff
+    window), only read. Returns (out (B,1,d), the token's new cache entry
+    {"k", "v"} of (B, Hkv, 1, D)); the caller writes it at slot
+    ``pos % Smax`` -- for a ring cache (local attention, Smax == window)
+    that evicts the oldest key."""
     b = x_t.shape[0]
     q, k, v = _project_qkv(p, x_t, cfg.n_heads, cfg.n_kv_heads, cfg.d_head)
     posb = jnp.broadcast_to(pos, (b, 1))
     q = L.rope(q, posb, cfg.rope_theta)
-    k = L.rope(k, posb, cfg.rope_theta)
-    s_max = cache["k"].shape[1]
-    # full cache: s_max > pos always so slot == pos; ring cache (local attn,
-    # s_max == window): the slot rotates and evicts the oldest key.
-    slot = pos % s_max
-    with jax.named_scope("kv_cache"):
-        k_cache = jax.lax.dynamic_update_slice(cache["k"], k, (0, slot, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(cache["v"], v, (0, slot, 0, 0))
-        slot_pos = jax.lax.dynamic_update_slice(
-            cache["slot_pos"], pos[None].astype(jnp.int32), (slot,)
-        )
+    k = L.rope(k, posb, cfg.rope_theta).swapaxes(1, 2)
+    v = v.swapaxes(1, 2)
     out = decode_attention(
-        q, k_cache, v_cache, slot_pos, pos, window=window, mma=cfg.mma_reductions
+        q, cache["k"], cache["v"], decode_valid(cache["slot_pos"], pos, window),
+        k, v, mma=cfg.mma_reductions,
     )
     out = P.dense_apply(p["o"], out.reshape(b, 1, -1))
-    return out, {"k": k_cache, "v": v_cache, "slot_pos": slot_pos}
+    return out, {"k": k, "v": v}
 
 
 def fill_kv_cache(p, x, positions, cache, cfg):
@@ -253,8 +288,9 @@ def fill_kv_cache(p, x, positions, cache, cfg):
     ring caches keep the last `window` positions)."""
     b, s, _ = x.shape
     _, k, v = _project_qkv(p, x, cfg.n_heads, cfg.n_kv_heads, cfg.d_head)
-    k = L.rope(k, positions, cfg.rope_theta)
-    s_max = cache["k"].shape[1]
+    k = L.rope(k, positions, cfg.rope_theta).swapaxes(1, 2)
+    v = v.swapaxes(1, 2)
+    s_max = cache["k"].shape[2]
     if s <= s_max:
         with jax.named_scope("kv_cache"):
             k_cache = jax.lax.dynamic_update_slice(cache["k"], k, (0, 0, 0, 0))
@@ -264,8 +300,8 @@ def fill_kv_cache(p, x, positions, cache, cfg):
         # later decode writes (slot = pos % s_max) evict oldest-first.
         tail = jnp.arange(s - s_max, s)
         perm = jnp.argsort(tail % s_max)  # perm[i] = tail index whose slot is i
-        k_cache = k[:, -s_max:][:, perm]
-        v_cache = v[:, -s_max:][:, perm]
+        k_cache = k[:, :, -s_max:][:, :, perm]
+        v_cache = v[:, :, -s_max:][:, :, perm]
         slot_pos = tail[perm].astype(jnp.int32)
     return {"k": k_cache, "v": v_cache, "slot_pos": slot_pos}
 

@@ -122,7 +122,9 @@ def mla_decode(p, x_t, cache, pos, cfg):
       out_h      = W_uv_h^T (sum_i p_h(i) c_i)
 
     so attention runs entirely in the R-dim latent space; per-step memory is
-    O(B*S*R) reads + O(B*H*R) temporaries.
+    O(B*S*R) reads + O(B*H*R) temporaries. The cache is only read; returns
+    (out, the token's new latent entry {"ckv": (B, 1, R + dr)}), which the
+    caller writes at position ``pos``.
     """
     m = cfg.mla
     h = cfg.n_heads
@@ -134,22 +136,23 @@ def mla_decode(p, x_t, cache, pos, cfg):
     q = P.dense_apply(p["q_up"], cq).reshape(b, 1, h, m.qk_nope_dim + m.qk_rope_dim)
     q_nope, q_rope = q[..., : m.qk_nope_dim], q[..., m.qk_nope_dim:]
     q_rope = L.rope(q_rope, posb, cfg.rope_theta)[:, 0]        # (B, H, dr)
-    # write this step's latent
+    # this step's latent: attended beside the cache, written by the caller
     ckv_full = P.dense_apply(p["kv_down"], x_t)
     k_rope_t = L.rope(
         ckv_full[..., m.kv_lora_rank:][:, :, None, :], posb, cfg.rope_theta
     )[:, :, 0, :]
     stored = jnp.concatenate([ckv_full[..., : m.kv_lora_rank], k_rope_t], -1)
-    ckv_cache = jax.lax.dynamic_update_slice(cache["ckv"], stored, (0, pos, 0))
-    slot_pos = jax.lax.dynamic_update_slice(
-        cache["slot_pos"], pos[None].astype(jnp.int32), (pos,)
-    )
-    # normalized latents + shared rope key, straight from the cache
-    c_all = L.norm_apply(
-        "rmsnorm", p["kv_norm"], ckv_cache[..., : m.kv_lora_rank],
-        eps=cfg.norm_eps, mma=cfg.mma_reductions,
-    )                                                           # (B, S, R)
-    k_rope_all = ckv_cache[..., m.kv_lora_rank:]                # (B, S, dr)
+    # normalized latents + shared rope key: the cache's, read where they
+    # lie, and the new token's
+    def latents(ckv):
+        c = L.norm_apply(
+            "rmsnorm", p["kv_norm"], ckv[..., : m.kv_lora_rank],
+            eps=cfg.norm_eps, mma=cfg.mma_reductions,
+        )
+        return c, ckv[..., m.kv_lora_rank:]
+
+    c_all, k_rope_all = latents(cache["ckv"])                   # (B, S, R)
+    c_new, k_rope_new = latents(stored)                         # (B, 1, R)
     # absorb W_uk into the query: q_c[b,h,r] = sum_d q_nope[b,h,d] Wuk[r,h,d]
     wkv = p["kv_up"]["w"].reshape(m.kv_lora_rank, h, m.qk_nope_dim + m.v_head_dim)
     w_uk, w_uv = wkv[..., : m.qk_nope_dim], wkv[..., m.qk_nope_dim:]
@@ -159,22 +162,32 @@ def mla_decode(p, x_t, cache, pos, cfg):
     q_c = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0].astype(jnp.float32),
                      w_uk.astype(jnp.float32))
     scale = (m.qk_nope_dim + m.qk_rope_dim) ** -0.5
-    s = (
-        jnp.einsum("bhr,bsr->bhs", q_c.astype(cd), c_all.astype(cd),
-                   preferred_element_type=jnp.float32)
-        + jnp.einsum("bhd,bsd->bhs", q_rope.astype(cd), k_rope_all.astype(cd),
-                     preferred_element_type=jnp.float32)
-    ) * scale
-    valid = (slot_pos >= 0) & (slot_pos <= pos)
-    s = jnp.where(valid[None, None], s, -1e30)
-    mx = jnp.max(s, -1, keepdims=True)
+
+    def scores(c, k_rope):
+        return (
+            jnp.einsum("bhr,bsr->bhs", q_c.astype(cd), c.astype(cd),
+                       preferred_element_type=jnp.float32)
+            + jnp.einsum("bhd,bsd->bhs", q_rope.astype(cd), k_rope.astype(cd),
+                         preferred_element_type=jnp.float32)
+        ) * scale
+
+    def weighted(e, c):
+        return jnp.einsum("bhs,bsr->bhr", e.astype(cd), c.astype(cd),
+                          preferred_element_type=jnp.float32)
+
+    # the cache's earlier positions and the new token in one softmax (as
+    # attention.decode_attention merges them)
+    valid = A.decode_valid(cache["slot_pos"], pos)
+    s = jnp.where(valid[None, None], scores(c_all, k_rope_all), -1e30)
+    s_new = scores(c_new, k_rope_new)                           # (B, H, 1)
+    mx = jnp.maximum(jnp.max(s, -1, keepdims=True), s_new)
     e = jnp.where(valid[None, None], jnp.exp(s - mx), 0.0)
+    e_new = jnp.exp(s_new - mx)
     from repro import reduce as R
 
     denom = R.reduce(e, axis=-1, backend=R.backend_for_flags(cfg.mma_reductions))
-    p_attn = e / jnp.maximum(denom, 1e-30)[..., None]           # (B, H, S)
-    o_lat = jnp.einsum("bhs,bsr->bhr", p_attn.astype(cd), c_all.astype(cd),
-                       preferred_element_type=jnp.float32)      # (B, H, R)
+    denom = jnp.maximum(denom + e_new[..., 0], 1e-30)[..., None]
+    o_lat = weighted(e / denom, c_all) + weighted(e_new / denom, c_new)  # (B, H, R)
     out_h = jnp.einsum("bhr,rhd->bhd", o_lat, w_uv.astype(jnp.float32))
     out = P.dense_apply(p["o"], out_h.reshape(b, 1, -1).astype(x_t.dtype))
-    return out, {"ckv": ckv_cache, "slot_pos": slot_pos}
+    return out, {"ckv": stored}

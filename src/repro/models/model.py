@@ -120,14 +120,16 @@ def block_train(kind, p, h, positions, cfg, ctx):
 def block_make_cache(kind, batch, s_max, cfg):
     if kind in ("attn", "local_attn"):
         if cfg.mla is not None:
-            return MLA.make_mla_cache(batch, s_max, cfg)
-        size = min(s_max, cfg.window) if (kind == "local_attn" and cfg.window) else s_max
+            return MLA.make_mla_cache(batch, A.cache_positions(s_max), cfg)
+        if kind == "local_attn" and cfg.window and cfg.window < s_max:
+            size = cfg.window  # ring: slot pos % window
+        else:
+            size = A.cache_positions(s_max)
         return A.make_kv_cache(batch, size, cfg.n_kv_heads, cfg.d_head, jnp.dtype(cfg.dtype))
     if kind == "xattn":
-        return {
-            "k": jnp.zeros((batch, cfg.n_img_tokens, cfg.n_kv_heads, cfg.d_head), jnp.dtype(cfg.dtype)),
-            "v": jnp.zeros((batch, cfg.n_img_tokens, cfg.n_kv_heads, cfg.d_head), jnp.dtype(cfg.dtype)),
-        }
+        shape = (batch, cfg.n_kv_heads, cfg.n_img_tokens, cfg.d_head)
+        return {"k": jnp.zeros(shape, jnp.dtype(cfg.dtype)),
+                "v": jnp.zeros(shape, jnp.dtype(cfg.dtype))}
     if kind == "ssm":
         return SSM.make_ssm_cache(batch, cfg)
     if kind == "rec":
@@ -156,7 +158,7 @@ def block_fill_cache(kind, p, h, positions, cache, cfg, ctx):
         b, n = ctx.shape[0], ctx.shape[1]
         k = P.dense_apply(p["mix"]["k"], ctx).reshape(b, n, cfg.n_kv_heads, cfg.d_head)
         v = P.dense_apply(p["mix"]["v"], ctx).reshape(b, n, cfg.n_kv_heads, cfg.d_head)
-        cache = {"k": k, "v": v}
+        cache = {"k": k.swapaxes(1, 2), "v": v.swapaxes(1, 2)}
         mix = A.cross_attention_apply(p["mix"], hn, ctx, cfg)
     elif kind == "ssm":
         mix, cache = SSM.ssm_train(p["mix"], hn, cfg, return_state=True)
@@ -176,33 +178,39 @@ def block_fill_cache(kind, p, h, positions, cache, cfg, ctx):
 
 
 def block_decode(kind, p, h, cache, pos, cfg, ctx):
+    """One block, one token. Returns (h, new): ``new`` is the token's entry
+    of a position-indexed cache (full K/V, ring K/V, MLA latent), only read
+    here and written by ``decode_step``; the whole new state of a recurrent
+    cache (SSM, RG-LRU); None for a cross-attention cache, which decode
+    only reads."""
     hn = _norm(p["norm1"], h, cfg)
     if kind in ("attn", "local_attn"):
         win = cfg.window if kind == "local_attn" else None
         if cfg.mla is not None:
-            mix, cache = MLA.mla_decode(p["mix"], hn, cache, pos, cfg)
+            mix, new = MLA.mla_decode(p["mix"], hn, cache, pos, cfg)
         else:
-            mix, cache = A.self_attention_decode(p["mix"], hn, cache, pos, cfg, window=win)
+            mix, new = A.self_attention_decode(p["mix"], hn, cache, pos, cfg, window=win)
     elif kind == "xattn":
         q = P.dense_apply(p["mix"]["q"], hn).reshape(
             hn.shape[0], 1, cfg.n_heads, cfg.d_head
         )
-        n = cache["k"].shape[1]
+        n = cache["k"].shape[2]
         out = A.decode_attention(
-            q, cache["k"], cache["v"], jnp.arange(n), jnp.asarray(n, jnp.int32),
+            q, cache["k"], cache["v"], jnp.ones((n,), bool),
             mma=cfg.mma_reductions,
         )
         mix = P.dense_apply(p["mix"]["o"], out.reshape(hn.shape[0], 1, -1))
         mix = jnp.tanh(p["mix"]["gate"].astype(jnp.float32)).astype(mix.dtype) * mix
+        new = None
     elif kind == "ssm":
-        mix, cache = SSM.ssm_decode(p["mix"], hn, cache, cfg)
+        mix, new = SSM.ssm_decode(p["mix"], hn, cache, cfg)
     elif kind == "rec":
-        mix, cache = REC.rglru_decode(p["mix"], hn, cache, cfg)
+        mix, new = REC.rglru_decode(p["mix"], hn, cache, cfg)
     h = h + mix
     if _has_ffn(kind):
         y, _ = _ffn_apply(p["ffn"], _norm(p["norm2"], h, cfg), cfg)
         h = h + y
-    return h, cache
+    return h, new
 
 
 # ------------------------------ full model ----------------------------------
@@ -425,42 +433,112 @@ def prefill(params, cfg: ModelConfig, tokens, caches, ctx=None):
     return _head_public(params, cfg, h[:, -1:]), out_caches
 
 
+def is_position_indexed(cache) -> bool:
+    """A block cache indexed by position (full K/V, ring K/V, MLA latent):
+    it carries ``slot_pos``, the position each slot holds."""
+    return "slot_pos" in cache
+
+
+def split_caches(caches):
+    """(position-indexed caches, the rest), each a caches tree of its own
+    blocks. A decode step writes one position of the first in place; the
+    rest it replaces (recurrent states) or only reads (cross-attention)."""
+    def part(keep):
+        return {grp: {k: c for k, c in blocks.items() if is_position_indexed(c) == keep}
+                for grp, blocks in caches.items()}
+
+    return part(True), part(False)
+
+
+def merge_caches(indexed, rest):
+    """The inverse of ``split_caches``."""
+    return {grp: {**indexed[grp], **rest[grp]} for grp in indexed}
+
+
+@jax.named_scope("kv_cache")
+def _write_position(cache, new, pos):
+    """A position-indexed block cache with ``new`` -- one entry per data
+    leaf, every layer's when stacked -- at slot ``pos % Smax`` of its
+    position axis (the second-to-last of each data leaf), and ``pos`` in
+    ``slot_pos``: one ``dynamic_update_slice`` per leaf, in place when the
+    cache is donated."""
+    slot = pos % cache["slot_pos"].shape[-1]
+    out = {}
+    for key, c in cache.items():
+        if key == "slot_pos":
+            x, axis = jnp.full(c.shape[:-1] + (1,), pos, c.dtype), c.ndim - 1
+        else:
+            x, axis = new[key].astype(c.dtype), c.ndim - 2
+        start = [0] * c.ndim
+        start[axis] = slot
+        out[key] = jax.lax.dynamic_update_slice(c, x, start)
+    return out
+
+
+def clear_position(caches, pos):
+    """``caches`` with zeros at position ``pos`` of every position-indexed
+    block cache, one ``dynamic_update_slice`` per leaf (in place when the
+    caches are donated). A decode step at ``pos`` weighs that position 0
+    but its dots still multiply it, and 0 * NaN is NaN: after clearing,
+    the step reads the same as it did before any attempt of it wrote
+    there."""
+    def clear(cache):
+        zeros = {k: jnp.zeros(c.shape[:-2] + (1,) + c.shape[-1:], c.dtype)
+                 for k, c in cache.items() if k != "slot_pos"}
+        return _write_position(cache, zeros, pos)
+
+    return {grp: {k: clear(c) if is_position_indexed(c) else c
+                  for k, c in blocks.items()}
+            for grp, blocks in caches.items()}
+
+
 def decode_step(params, cfg: ModelConfig, token_t, caches, pos, ctx=None):
     """One token step. token_t: (B, 1) or (B, 1, K); pos: scalar int32.
     Returns (logits (B,1,...), new_caches).
 
-    The stacked unit caches travel in the scan CARRY and are updated with
-    dynamic_update_index -- a single buffer XLA updates in place. (Passing
-    them as scan xs/ys double-buffers the whole KV cache per step: +8 GB/dev
-    on deepseek decode_32k, caught by the dry-run memory analysis.)"""
+    The layer scan carries only the hidden state. The stacked caches enter
+    it as ``xs``, read where they lie: each layer attends over its cache's
+    positions before ``pos`` and the token's own key/value, which it
+    returns as its small ``ys`` (one position, (B, Hkv, 1, D) a layer);
+    recurrent states come back whole, as ``ys`` of their own O(B * state)
+    size, and cross-attention caches are only read. After the scan each
+    position-indexed leaf gets every layer's new entry, and ``slot_pos``
+    its ``pos``, in one ``dynamic_update_slice`` at position ``pos``
+    (``_write_position``). No layer's cache is sliced out or written back
+    and no whole cache is double-buffered: with the caches donated
+    (``launch.serve``) the write lands in place, and the step touches no
+    position but ``pos``. Position ``pos`` is never attended from the
+    cache, so a step re-run on caches an earlier attempt of it wrote into
+    (the runtime's retry) reads what the first attempt read, once
+    ``clear_position`` has zeroed what that attempt left there."""
     pat, n_units, tail = _pattern_units(cfg)
     h = _embed(params, cfg, token_t)
 
-    def unit_fn(carry, xs):
-        hh, stacked = carry
-        unit_params, i = xs
-        unit_cache = _cache_layer(stacked, i)
-        new_cache = {}
+    def write(cache, new):
+        if is_position_indexed(cache):
+            return _write_position(cache, new, pos)
+        return cache if new is None else new
+
+    def unit_fn(hh, xs):
+        unit_params, unit_cache = xs
+        new = {}
         for j, kind in enumerate(pat):
-            hh, new_cache[f"pos{j}"] = block_decode(
+            hh, new[f"pos{j}"] = block_decode(
                 kind, unit_params[f"pos{j}"], hh, unit_cache[f"pos{j}"], pos, cfg, ctx
             )
             hh = CTX.constrain(hh)
-        stacked = _cache_set_layer(stacked, new_cache, i)
-        return (hh, stacked), None
+        return hh, new
 
-    (h, new_units), _ = jax.lax.scan(
-        unit_fn, (h, caches["units"]),
-        (params["units"], jnp.arange(n_units)),
-    )
-    out_caches = {"units": new_units}
+    h, new_units = jax.lax.scan(unit_fn, h, (params["units"], caches["units"]))
+    out_caches = {"units": {
+        k: write(c, new_units[k]) for k, c in caches["units"].items()
+    }}
     if tail:
         tc = {}
         for i, kind in enumerate(tail):
-            h, tc[f"pos{i}"] = block_decode(
-                kind, params["tail"][f"pos{i}"], h, caches["tail"][f"pos{i}"],
-                pos, cfg, ctx,
-            )
+            c = caches["tail"][f"pos{i}"]
+            h, new = block_decode(kind, params["tail"][f"pos{i}"], h, c, pos, cfg, ctx)
+            tc[f"pos{i}"] = write(c, new)
         out_caches["tail"] = tc
     h = _norm(params["final_norm"], h, cfg)
     return _head_public(params, cfg, h), out_caches

@@ -119,7 +119,9 @@ class ServeMetrics(GuardMetrics):
     Admission (admitted/shed_queue_full/shed_infeasible), deadline misses,
     per-slot quarantines, breaker trips + live per-backend breaker states,
     completed requests/tokens, slot-steps run and slot-steps that received
-    a token (``slot_steps``/``live_slot_steps``), and the time to first
+    a token (``slot_steps``/``live_slot_steps``), the engine's decode calls
+    that wrote their donated caches in place (``decode_in_place``, read
+    from the engine at every export), and the time to first
     token and gaps between tokens of the newest ``request_window``
     requests, summarized as p50/p99 in the snapshot. Everything exports
     through the same atomic-JSON ``write()`` (``--status-path``) the
@@ -138,6 +140,7 @@ class ServeMetrics(GuardMetrics):
         self.tokens_out = 0
         self.slot_steps = 0
         self.live_slot_steps = 0
+        self.decode_in_place = 0
         self.breaker_states: dict = {}
         # (ttft, [gaps]) of the newest requests: a long-running server's
         # tail stays current, not lifetime-averaged
@@ -201,6 +204,7 @@ class ServeMetrics(GuardMetrics):
                 "tokens_out": self.tokens_out,
                 "slot_steps": self.slot_steps,
                 "live_slot_steps": self.live_slot_steps,
+                "decode_in_place": self.decode_in_place,
                 "ttft_p50_s": _percentile(ttft, 0.50),
                 "ttft_p99_s": _percentile(ttft, 0.99),
                 "itl_p50_s": _percentile(itl, 0.50),

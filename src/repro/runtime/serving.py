@@ -63,10 +63,17 @@ Engine protocol::
 slot); ``scales`` a per-slot float multiplier applied to the slot's logits
 (1.0 = bitwise identity -- the chaos hook); ``tokens`` per-slot ints;
 ``census`` the per-slot non-finite counts with the total in the last slot
-(``guarded_logit_stat``'s layout). Steps must be FUNCTIONAL: the runtime
-re-issues a step from the same ``state`` on retry, so an engine must not
-mutate caches in place. Faults raise ``TransientFault`` (charged to the
-breaker) or ``Preemption`` (retried free).
+(``guarded_logit_stat``'s layout). The runtime re-issues a step from
+the same (committed) ``state`` on retry, so a step may change that state
+only where a retry from it still gives the clean step's result bitwise:
+``launch.serve.GuardedEngine``'s decode writes position ``pos`` of its
+donated position-indexed caches in place and moves the written buffers
+into ``state``; on a retry it zeroes that position first, and writes it
+again. Everything else a step reads -- tokens, positions, recurrent
+states -- it must leave as it was. An engine with a ``decode_in_place``
+count reports it through ``ServeMetrics`` at every export. Faults raise
+``TransientFault`` (charged to the breaker) or ``Preemption`` (retried
+free).
 """
 
 from __future__ import annotations
@@ -650,6 +657,7 @@ class ServingRuntime:
 
     def _export(self) -> None:
         self.metrics.breaker_trips = self.breaker.total_trips
+        self.metrics.decode_in_place = getattr(self.engine, "decode_in_place", 0)
         self.metrics.record_breaker_states(self.breaker.states())
         if self.status_path is not None:
             self.metrics.write(self.status_path)

@@ -15,6 +15,7 @@ compiles (an entry written here cannot be read back without a chip).
 
 from __future__ import annotations
 
+import dataclasses
 import os
 
 import jax
@@ -250,3 +251,45 @@ def test_full_width_olmo_train_step_fits_one_v5e(one_chip, compiled_kernels):
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert need < V5E_HBM, need / GiB
+
+
+def test_guarded_decode_step_writes_cache_in_place_on_v5e(one_chip,
+                                                          compiled_kernels):
+    """The guarded decode step at internlm2-1.8b's widths (two of its
+    layers) with the chat cell's 48 slots of 1,025 positions: every cache
+    leaf is aliased to an output, and the step's temporaries hold neither a
+    copy of the cache nor one layer's slice of it."""
+    from repro.launch.serve import GuardedEngine
+    from repro.models import make_caches
+    from repro.models.model import split_caches
+
+    cfg = dataclasses.replace(get_arch("internlm2-1.8b"), n_layers=2)
+    slots, s_max = 48, 1025
+    eng = object.__new__(GuardedEngine)  # its shapes only: no weights made
+    eng.cfg, eng.s_max, eng.slots, eng.ctx = cfg, s_max, slots, None
+    eng._guarded_decode = {}
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda k: init_params(k, cfg)[0], jax.random.PRNGKey(0)))
+    indexed, rest = split_caches(on_chip(jax.eval_shape(
+        lambda: make_caches(cfg, slots, s_max))))
+    lowered = eng._decode_fn("pallas_fused").lower(
+        params, indexed, rest,
+        jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((slots,), jnp.float32, sharding=one_chip))
+    leaves = jax.tree.leaves(indexed)
+    assert lowered.as_text().count("tf.aliasing_output") == len(leaves)
+    compiled = lowered.compile()
+    _assert_kernel(compiled)
+    mem = compiled.memory_analysis()
+    # the chip's tiles pad the small ``slot_pos`` leaf
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize
+                                          for a in leaves)
+    layer_kv = max(a.size * a.dtype.itemsize for a in leaves) // cfg.n_layers
+    assert mem.temp_size_in_bytes < layer_kv // 8, mem.temp_size_in_bytes

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.models.model import split_caches
 from repro.runtime import (
     ChaosMonkey,
     Completion,
@@ -255,13 +256,15 @@ def test_guarded_serve_steps_carry_model_cache_census_scopes():
         lambda p, t, s: eng._prefill_fn("xla")(p, t, s)[1],
         eng.params, packed, scales)
     dec = _op_names(eng._decode_fn("xla").lower(
-        eng.params, caches, tok, jnp.asarray(8, jnp.int32), scales))
+        eng.params, *split_caches(caches), tok, jnp.asarray(8, jnp.int32),
+        scales))
     for names in (pre, dec):
         assert {"model", "census"} <= _top_scopes(names)
         cache = [n for n in names if "/kv_cache/" in n]
         # the cache's reads and writes, inside the model
         assert cache and all(n.startswith("jit(step)/model/") for n in cache)
+    # decode writes one position of the cache and slices no layer out of it
     assert any(n.endswith("kv_cache/dynamic_update_slice")
                for n in dec if "/kv_cache/" in n)
-    assert any(n.endswith("kv_cache/dynamic_slice")
-               for n in dec if "/kv_cache/" in n)
+    assert not any(n.endswith("kv_cache/dynamic_slice")
+                   for n in dec if "/kv_cache/" in n)
