@@ -10,6 +10,7 @@ from repro.configs import (
     base,
     dbrx_132b,
     deepseek_7b,
+    deepseek_v2_lite,
     granite_moe_1b,
     internlm2_1_8b,
     llama32_vision_11b,
@@ -32,6 +33,7 @@ from repro.configs.base import (  # noqa: F401
     SSMConfig,
     ShapeConfig,
     TrainConfig,
+    YaRNConfig,
     shape_applicable,
 )
 
@@ -46,6 +48,7 @@ _MODULES = (
     internlm2_1_8b,
     recurrentgemma_9b,
     llama32_vision_11b,
+    deepseek_v2_lite,
 )
 
 ARCHS: dict[str, ModelConfig] = {m.CONFIG.name: m.CONFIG for m in _MODULES}

@@ -13,12 +13,33 @@ from typing import Optional
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """Routed experts (``n_experts`` router outputs, ``top_k`` a token),
+    optional shared experts that every token passes through (acting as one
+    SwiGLU of ``n_shared_experts * d_ff_expert``), and the share of the
+    routed experts this device holds: experts ``held_start`` to
+    ``held_start + n_held - 1`` (``held_count`` None: all of them). Routing
+    is over all ``n_experts``; pairs routed to experts held elsewhere add
+    nothing here (expert parallelism, one device's part)."""
+
     n_experts: int
     top_k: int
     d_ff_expert: int
     capacity_factor: float = 1.25
     aux_loss_weight: float = 0.01
     router_z_weight: float = 1e-3
+    n_shared_experts: int = 0
+    norm_topk_prob: bool = True       # renormalise the top-k gates to sum 1
+    routed_scaling_factor: float = 1.0
+    held_start: int = 0
+    held_count: Optional[int] = None
+
+    @property
+    def n_held(self) -> int:
+        return self.n_experts if self.held_count is None else self.held_count
+
+    @property
+    def holds_all(self) -> bool:
+        return self.held_start == 0 and self.n_held == self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -37,13 +58,37 @@ class SSMConfig:
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    """Multi-head Latent Attention (DeepSeek-V2 / MiniCPM3)."""
+    """Multi-head Latent Attention (DeepSeek-V2 / MiniCPM3).
 
-    q_lora_rank: int = 768
+    ``q_lora_rank`` None projects the query directly from the hidden state
+    (DeepSeek-V2-Lite); ``rope_interleave`` takes the rope dims as
+    interleaved pairs and permutes them to rotate-half order before
+    rotating, as DeepSeek-V2's ``apply_rotary_pos_emb`` does."""
+
+    q_lora_rank: Optional[int] = 768
     kv_lora_rank: int = 256
     qk_nope_dim: int = 64
     qk_rope_dim: int = 32
     v_head_dim: int = 64
+    rope_interleave: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rope scaling (Peng et al. 2023) as DeepSeek-V2 configures it:
+    inverse frequencies blended between the interpolated (``/ factor``)
+    and the original ones by a linear ramp over the dims whose rotations
+    in ``original_max_position`` lie between ``beta_slow`` and
+    ``beta_fast``; cos/sin scaled by mscale(mscale) / mscale(mscale_all_dim)
+    and the softmax scale by mscale(mscale_all_dim) ** 2, where
+    mscale(m) = 0.1 * m * ln(factor) + 1."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,6 +120,10 @@ class ModelConfig:
     ffn_kind: str = "swiglu"       # swiglu | gelu
     window: Optional[int] = None   # local_attn window size
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YaRNConfig] = None
+    # the first ``first_k_dense`` layers are dense (SwiGLU of ``d_ff``)
+    # blocks outside the stacked units; the rest use ``moe`` when set
+    first_k_dense: int = 0
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     mla: Optional[MLAConfig] = None
@@ -90,6 +139,16 @@ class ModelConfig:
     mma_reductions: bool = True    # paper's technique on/off (off = baseline)
     remat: bool = True             # activation checkpointing per layer-unit
     logits_softcap: float = 0.0
+
+    def __post_init__(self):
+        # built from JSON too: nested configs as dicts, tuples as lists
+        for name, cls in (("moe", MoEConfig), ("ssm", SSMConfig),
+                          ("mla", MLAConfig), ("rglru", RGLRUConfig),
+                          ("rope_scaling", YaRNConfig)):
+            v = getattr(self, name)
+            if isinstance(v, dict):
+                object.__setattr__(self, name, cls(**v))
+        object.__setattr__(self, "block_pattern", tuple(self.block_pattern))
 
     @property
     def pattern_layers(self) -> tuple[str, ...]:
@@ -117,12 +176,15 @@ class ModelConfig:
             total += (self.n_codebooks - 1) * self.vocab_size * d
         if not self.tie_embeddings:
             total += self.vocab_size * d * max(1, self.n_codebooks or 1)
-        for kind in self.pattern_layers:
+        for i, kind in enumerate(self.pattern_layers):
             if kind in ("attn", "local_attn"):
                 if self.mla is not None:
                     m = self.mla
-                    total += d * m.q_lora_rank
-                    total += m.q_lora_rank * self.n_heads * (m.qk_nope_dim + m.qk_rope_dim)
+                    qk = self.n_heads * (m.qk_nope_dim + m.qk_rope_dim)
+                    if m.q_lora_rank is None:
+                        total += d * qk
+                    else:
+                        total += d * m.q_lora_rank + m.q_lora_rank * qk
                     total += d * (m.kv_lora_rank + m.qk_rope_dim)
                     total += m.kv_lora_rank * self.n_heads * (m.qk_nope_dim + m.v_head_dim)
                     total += self.n_heads * m.v_head_dim * d
@@ -130,7 +192,7 @@ class ModelConfig:
                     total += d * self.n_heads * self.d_head
                     total += 2 * d * self.n_kv_heads * self.d_head
                     total += self.n_heads * self.d_head * d
-                total += self._ffn_params()
+                total += self._ffn_params(dense=i < self.first_k_dense)
             elif kind == "xattn":
                 total += d * self.n_heads * self.d_head
                 total += 2 * d * self.n_kv_heads * self.d_head
@@ -152,13 +214,16 @@ class ModelConfig:
                 total += self._ffn_params()
         return int(total)
 
-    def _ffn_params(self) -> int:
+    def _ffn_params(self, dense: bool = False) -> int:
+        """One layer's feed-forward: a MoE layer holds its experts (those
+        of this device's share), the shared experts and the router."""
         d = self.d_model
-        if self.moe is not None:
+        mats = 3 if self.ffn_kind == "swiglu" else 2
+        if self.moe is not None and not dense:
             e = self.moe
-            per = 3 * d * e.d_ff_expert if self.ffn_kind == "swiglu" else 2 * d * e.d_ff_expert
-            return e.n_experts * per + d * e.n_experts
-        return 3 * d * self.d_ff if self.ffn_kind == "swiglu" else 2 * d * self.d_ff
+            per = mats * d * e.d_ff_expert
+            return (e.n_held + e.n_shared_experts) * per + d * e.n_experts
+        return mats * d * self.d_ff
 
     def active_param_count(self) -> int:
         """Params touched per token (MoE: only top-k experts) -- the N in
@@ -168,10 +233,11 @@ class ModelConfig:
         e = self.moe
         total = self.param_count()
         per = (3 if self.ffn_kind == "swiglu" else 2) * self.d_model * e.d_ff_expert
-        n_ffn_layers = sum(
-            1 for k in self.pattern_layers if k in ("attn", "local_attn", "xattn")
+        n_moe_layers = sum(
+            1 for i, k in enumerate(self.pattern_layers)
+            if k in ("attn", "local_attn", "xattn") and i >= self.first_k_dense
         )
-        total -= n_ffn_layers * (e.n_experts - e.top_k) * per
+        total -= n_moe_layers * (e.n_held - e.top_k) * per
         return int(total)
 
 

@@ -171,7 +171,14 @@ class GuardedEngine(Engine):
     started from, and the step returns new ones. Prefill makes its caches
     inside the step. ``decode_in_place`` counts the decode calls whose
     donated caches were consumed (deleted after the call); a backend that
-    declined the donation shows at once as a count below the calls."""
+    declined the donation shows at once as a count below the calls.
+
+    For a MoE configuration, ``moe_held_pairs`` counts the routed (token,
+    expert) pairs the decode calls computed on this device's experts, and
+    ``moe_decode_calls`` those calls. The step sums its pairs on the device
+    and appends the sum to the census it returns anyway; ``decode`` takes
+    it off before the runtime sees the census, so the count costs no
+    transfer of its own."""
 
     def __init__(self, cfg, s_max: int, batch_slots: int, seed: int = 0):
         super().__init__(cfg, s_max, batch_slots, seed)
@@ -179,6 +186,12 @@ class GuardedEngine(Engine):
         self._guarded_decode = {}
         self._clear = jax.jit(clear_position, donate_argnums=(0,))
         self.decode_in_place = 0
+        self.moe_held_pairs = 0
+        self.moe_decode_calls = 0
+
+    @property
+    def _moe(self) -> bool:
+        return self.cfg.moe is not None
 
     def validate(self, prompt, max_new: int):
         try:
@@ -215,14 +228,18 @@ class GuardedEngine(Engine):
         fn = self._guarded_decode.get(backend)
         if fn is not None:
             return fn
-        decode_logits = make_decode_step(self.cfg, greedy=False)
+        decode_logits = make_decode_step(self.cfg, greedy=False,
+                                         with_stats=self._moe)
 
         def step(params, indexed, rest, tok, pos, scales, ctx=None):
-            logits, caches = decode_logits(
+            logits, caches, *stats = decode_logits(
                 params, merge_caches(indexed, rest), tok, pos, ctx)
             logits = self._scale_logits(logits, scales)
             with jax.named_scope("census"):
                 stat, census = guarded_logit_stat(logits, backend=backend)
+            if self._moe:
+                pairs = stats[0]["moe_held_pairs"].astype(census.dtype)
+                census = jnp.concatenate([census, pairs[None]])
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)
             return (nxt,) + split_caches(caches) + (stat, census)
 
@@ -270,7 +287,12 @@ class GuardedEngine(Engine):
         state["attempted"] = True
         new_state = {"caches": merge_caches(indexed_new, rest_new), "tok": tok,
                      "pos": state["pos"], "t": state["t"] + 1}
-        return (new_state,) + _read_back(tok, census)
+        tok, census = _read_back(tok, census)
+        if self._moe:
+            self.moe_held_pairs += int(census[-1])
+            self.moe_decode_calls += 1
+            census = census[:-1]
+        return new_state, tok, census
 
 
 def main(argv=None):
@@ -355,6 +377,9 @@ def main(argv=None):
               f"p99={snap['itl_p99_s'] * 1e3:.1f}ms  "
               f"live slots {snap['live_slot_steps']}/{snap['slot_steps']}  "
               f"decode in place {snap['decode_in_place']}")
+        if cfg.moe is not None:
+            print(f"moe routed pairs on held experts {snap['moe_held_pairs']}"
+                  f" in {snap['moe_decode_calls']} decode calls")
         return results, snap
     eng = Engine(cfg, s_max, args.batch_slots)
     outs = eng.serve(reqs, args.max_new)

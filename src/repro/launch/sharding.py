@@ -143,12 +143,16 @@ def cache_shardings(caches_shape, cfg, mesh: Mesh):
 
     k/v:   (B, Hkv, S, D)   -> (batch, model*, None, None)
     ckv:   (B, S, R)        -> (batch, None, None)      [MLA latent]
+    k_rope: (B, S, dr)      -> (batch, None, None)      [MLA rope key]
     conv:  (B, K-1, C)      -> (batch, None, model)
     state: (B, H, P, N)     -> (batch, model, None, None)  [SSD]
     h:     (B, W)           -> (batch, model)              [RG-LRU]
     slot_pos: replicated
     (* only when the head count divides the model axis -- MQA kv=1 and
      dbrx kv=8 fall back to replicated-or-padded per GSPMD.)
+    Stacked caches (under ``units``) carry a leading layer axis that is
+    never sharded; the unstacked ``lead`` (leading dense layers) and
+    ``tail`` caches have none.
     """
     from repro.launch.mesh import batch_axes
 
@@ -183,7 +187,7 @@ def cache_shardings(caches_shape, cfg, mesh: Mesh):
                 s = P(bspec, "model", None, None)
             else:
                 s = P(bspec, None, mdl(2), None)
-        elif name == "ckv":
+        elif name in ("ckv", "k_rope"):
             # MLA latent: split-KV over sequence (attention contracts s)
             s = P(bspec, mdl(1), None)
         elif name == "conv":

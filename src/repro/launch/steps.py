@@ -330,15 +330,20 @@ def make_prefill_step(cfg: ModelConfig, s_max: int):
     return prefill_step
 
 
-def make_decode_step(cfg: ModelConfig, greedy: bool = True):
+def make_decode_step(cfg: ModelConfig, greedy: bool = True,
+                     with_stats: bool = False):
+    """``decode_one(params, caches, token, pos[, ctx]) -> (next token or
+    logits, caches)``, and with ``with_stats`` the step's counters
+    (``models.model.decode_step``) as a third item."""
     def decode_one(params, caches, token, pos, ctx=None):
         with jax.named_scope("model"):
-            logits, caches = model_decode(params, cfg, token, caches, pos,
-                                          ctx)
+            out = model_decode(params, cfg, token, caches, pos, ctx,
+                               with_stats=with_stats)
+        logits, caches = out[:2]
         if greedy:
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)
         else:
             nxt = logits
-        return nxt, caches
+        return (nxt, caches) + tuple(out[2:])
 
     return decode_one

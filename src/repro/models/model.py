@@ -5,7 +5,11 @@ A config's ``block_pattern`` (e.g. ("rec","rec","attn") for RecurrentGemma,
 n_layers. Layers are grouped into *units* of one pattern period; unit params
 are stacked on a leading axis and the stack is driven by ``lax.scan`` so the
 HLO -- and the 512-device dry-run compile time -- stays flat in depth. A
-partial tail unit (e.g. RecurrentGemma's 38 = 12*3 + 2) is applied unrolled.
+partial tail unit (e.g. RecurrentGemma's 38 = 12*3 + 2) is applied unrolled,
+and so are ``cfg.first_k_dense`` leading layers whose feed-forward is a
+dense SwiGLU of ``d_ff`` where the others are MoE (DeepSeek-V2's dense
+first layer): they sit outside the stacked units, under ``lead``, with
+caches of their own.
 
 Three entry points with one parameter tree:
   forward      (B, S) tokens -> logits           train / teacher-forcing
@@ -42,19 +46,26 @@ def _has_ffn(kind: str) -> bool:
     return kind in ("attn", "local_attn", "xattn", "rec")
 
 
-def _ffn_init(key, cfg):
-    if cfg.moe is not None:
+def _ffn_init(key, cfg, dense=False):
+    if cfg.moe is not None and not dense:
         return MOE.moe_init(key, cfg)
     return L.ffn_init(key, cfg.d_model, cfg.d_ff, cfg.ffn_kind, jnp.dtype(cfg.dtype))
 
 
-def _ffn_apply(p, h, cfg):
-    if cfg.moe is not None:
+def _ffn_apply(p, h, cfg, dense=False, dropless=False):
+    """The block's feed-forward -> (y, metrics). A MoE layer routes with
+    the training path's capacity dispatch, or with ``dropless`` (serving:
+    prefill and decode) the dispatch that drops no token. A layer that
+    holds only a share of the experts always routes dropless: the
+    capacity dispatch computes every expert."""
+    if cfg.moe is not None and not dense:
+        if dropless or not cfg.moe.holds_all:
+            return MOE.moe_apply_dropless(p, h, cfg)
         return MOE.moe_apply(p, h, cfg)
     return L.ffn_apply(p, h, cfg.ffn_kind), {}
 
 
-def block_init(kind: str, key, cfg: ModelConfig):
+def block_init(kind: str, key, cfg: ModelConfig, dense=False):
     ks = P.split(key, 4)
     dt = jnp.dtype(cfg.dtype)
     d = cfg.d_model
@@ -79,7 +90,7 @@ def block_init(kind: str, key, cfg: ModelConfig):
         raise ValueError(kind)
     if _has_ffn(kind):
         p["norm2"], a["norm2"] = P.norm_init(cfg.norm, d, dt)
-        p["ffn"], a["ffn"] = _ffn_init(ks[1], cfg)
+        p["ffn"], a["ffn"] = _ffn_init(ks[1], cfg, dense)
     return p, a
 
 
@@ -90,7 +101,7 @@ def _norm(p, h, cfg):
     )
 
 
-def block_train(kind, p, h, positions, cfg, ctx):
+def block_train(kind, p, h, positions, cfg, ctx, dense=False):
     """One block, train/prefill compute. Returns (h, aux_loss_scalar)."""
     hn = _norm(p["norm1"], h, cfg)
     if kind in ("attn", "local_attn"):
@@ -108,7 +119,7 @@ def block_train(kind, p, h, positions, cfg, ctx):
     h = h + mix
     aux = jnp.zeros((), jnp.float32)
     if _has_ffn(kind):
-        y, metrics = _ffn_apply(p["ffn"], _norm(p["norm2"], h, cfg), cfg)
+        y, metrics = _ffn_apply(p["ffn"], _norm(p["norm2"], h, cfg), cfg, dense)
         h = h + y
         aux = aux + sum(
             (v for k, v in metrics.items() if k in ("moe_aux", "moe_z")),
@@ -137,7 +148,7 @@ def block_make_cache(kind, batch, s_max, cfg):
     raise ValueError(kind)
 
 
-def block_fill_cache(kind, p, h, positions, cache, cfg, ctx):
+def block_fill_cache(kind, p, h, positions, cache, cfg, ctx, dense=False):
     """Prefill: run the block AND populate its cache. Returns (h, aux, cache).
 
     The mixer input is norm1(h); caches are filled from exactly that stream,
@@ -168,7 +179,8 @@ def block_fill_cache(kind, p, h, positions, cache, cfg, ctx):
         raise ValueError(kind)
     h = h + mix
     if _has_ffn(kind):
-        y, metrics = _ffn_apply(p["ffn"], _norm(p["norm2"], h, cfg), cfg)
+        y, metrics = _ffn_apply(p["ffn"], _norm(p["norm2"], h, cfg), cfg,
+                                dense, dropless=True)
         h = h + y
         aux = aux + sum(
             (v for k, v in metrics.items() if k in ("moe_aux", "moe_z")),
@@ -177,12 +189,13 @@ def block_fill_cache(kind, p, h, positions, cache, cfg, ctx):
     return h, aux, cache
 
 
-def block_decode(kind, p, h, cache, pos, cfg, ctx):
-    """One block, one token. Returns (h, new): ``new`` is the token's entry
-    of a position-indexed cache (full K/V, ring K/V, MLA latent), only read
-    here and written by ``decode_step``; the whole new state of a recurrent
-    cache (SSM, RG-LRU); None for a cross-attention cache, which decode
-    only reads."""
+def block_decode(kind, p, h, cache, pos, cfg, ctx, dense=False):
+    """One block, one token. Returns (h, new, stats): ``new`` is the token's
+    entry of a position-indexed cache (full K/V, ring K/V, MLA latent), only
+    read here and written by ``decode_step``; the whole new state of a
+    recurrent cache (SSM, RG-LRU); None for a cross-attention cache, which
+    decode only reads. ``stats`` holds a MoE layer's ``moe_held_pairs``
+    (empty for any other layer)."""
     hn = _norm(p["norm1"], h, cfg)
     if kind in ("attn", "local_attn"):
         win = cfg.window if kind == "local_attn" else None
@@ -207,20 +220,31 @@ def block_decode(kind, p, h, cache, pos, cfg, ctx):
     elif kind == "rec":
         mix, new = REC.rglru_decode(p["mix"], hn, cache, cfg)
     h = h + mix
+    stats = {}
     if _has_ffn(kind):
-        y, _ = _ffn_apply(p["ffn"], _norm(p["norm2"], h, cfg), cfg)
+        y, metrics = _ffn_apply(p["ffn"], _norm(p["norm2"], h, cfg), cfg,
+                                dense, dropless=True)
         h = h + y
-    return h, new
+        stats = {k: v for k, v in metrics.items() if k == "moe_held_pairs"}
+    return h, new, stats
 
 
 # ------------------------------ full model ----------------------------------
 
 
 def _pattern_units(cfg: ModelConfig):
+    """(pattern, stacked units, tail kinds) of the layers after the
+    ``first_k_dense`` leading ones."""
     pat = tuple(cfg.block_pattern)
-    n_units = cfg.n_layers // len(pat)
-    tail = tuple(pat[: cfg.n_layers % len(pat)])
+    n = cfg.n_layers - cfg.first_k_dense
+    n_units = n // len(pat)
+    tail = tuple(pat[: n % len(pat)])
     return pat, n_units, tail
+
+
+def _lead_kinds(cfg: ModelConfig):
+    """The kinds of the leading dense layers (the pattern's first ones)."""
+    return cfg.pattern_layers[: cfg.first_k_dense]
 
 
 def init_params(key, cfg: ModelConfig):
@@ -247,6 +271,14 @@ def init_params(key, cfg: ModelConfig):
             ps[f"pos{i}"], as_[f"pos{i}"] = block_init(kind, kks[i], cfg)
         return ps, as_
 
+    lead = _lead_kinds(cfg)
+    if lead:
+        lp, la = {}, {}
+        lks = P.split(ks[4], len(lead))
+        for i, kind in enumerate(lead):
+            lp[f"pos{i}"], la[f"pos{i}"] = block_init(kind, lks[i], cfg,
+                                                      dense=True)
+        params["lead"], axes["lead"] = lp, la
     params["units"], axes["units"] = P.stack_init(unit_init, ks[1], n_units)
     if tail:
         tp, ta = {}, {}
@@ -325,6 +357,12 @@ def forward_hidden(params, cfg: ModelConfig, tokens, ctx=None):
     h = CTX.constrain(_embed(params, cfg, tokens))
     b, s = h.shape[0], h.shape[1]
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
+    aux0 = jnp.zeros((), jnp.float32)
+    for i, kind in enumerate(_lead_kinds(cfg)):
+        h, a = block_train(kind, params["lead"][f"pos{i}"], h, positions, cfg,
+                           ctx, dense=True)
+        h = CTX.constrain(h)
+        aux0 = aux0 + a
 
     def unit_fn(carry, unit_params):
         hh, aux = carry
@@ -335,7 +373,7 @@ def forward_hidden(params, cfg: ModelConfig, tokens, ctx=None):
         return (hh, aux), None
 
     body = jax.checkpoint(unit_fn) if cfg.remat else unit_fn
-    (h, aux), _ = jax.lax.scan(body, (h, jnp.zeros((), jnp.float32)), params["units"])
+    (h, aux), _ = jax.lax.scan(body, (h, aux0), params["units"])
     for i, kind in enumerate(tail):
         h, a = block_train(kind, params["tail"][f"pos{i}"], h, positions, cfg, ctx)
         h = CTX.constrain(h)
@@ -365,6 +403,11 @@ def make_caches(cfg: ModelConfig, batch: int, s_max: int):
         unit_cache(None),
     )
     caches = {"units": units}
+    if _lead_kinds(cfg):
+        caches["lead"] = {
+            f"pos{i}": block_make_cache(kind, batch, s_max, cfg)
+            for i, kind in enumerate(_lead_kinds(cfg))
+        }
     if tail:
         caches["tail"] = {
             f"pos{i}": block_make_cache(kind, batch, s_max, cfg)
@@ -400,6 +443,12 @@ def prefill(params, cfg: ModelConfig, tokens, caches, ctx=None):
     h = _embed(params, cfg, tokens)
     b, s = h.shape[0], h.shape[1]
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
+    lead = {}
+    for i, kind in enumerate(_lead_kinds(cfg)):
+        h, _, lead[f"pos{i}"] = block_fill_cache(
+            kind, params["lead"][f"pos{i}"], h, positions,
+            caches["lead"][f"pos{i}"], cfg, ctx, dense=True,
+        )
 
     def unit_fn(carry, xs):
         hh, aux, stacked = carry
@@ -421,6 +470,8 @@ def prefill(params, cfg: ModelConfig, tokens, caches, ctx=None):
         (params["units"], jnp.arange(n_units)),
     )
     out_caches = {"units": new_units}
+    if lead:
+        out_caches["lead"] = lead
     if tail:
         tc = {}
         for i, kind in enumerate(tail):
@@ -492,9 +543,13 @@ def clear_position(caches, pos):
             for grp, blocks in caches.items()}
 
 
-def decode_step(params, cfg: ModelConfig, token_t, caches, pos, ctx=None):
+def decode_step(params, cfg: ModelConfig, token_t, caches, pos, ctx=None,
+                with_stats=False):
     """One token step. token_t: (B, 1) or (B, 1, K); pos: scalar int32.
-    Returns (logits (B,1,...), new_caches).
+    Returns (logits (B,1,...), new_caches), and with ``with_stats`` a third
+    item: the step's counters summed over layers (``moe_held_pairs``, the
+    routed pairs computed on this device's experts, for a MoE config;
+    empty otherwise).
 
     The layer scan carries only the hidden state. The stacked caches enter
     it as ``xs``, read where they lie: each layer attends over its cache's
@@ -513,32 +568,56 @@ def decode_step(params, cfg: ModelConfig, token_t, caches, pos, ctx=None):
     ``clear_position`` has zeroed what that attempt left there."""
     pat, n_units, tail = _pattern_units(cfg)
     h = _embed(params, cfg, token_t)
+    stats = []
 
     def write(cache, new):
         if is_position_indexed(cache):
             return _write_position(cache, new, pos)
         return cache if new is None else new
 
+    out_caches = {}
+    if _lead_kinds(cfg):
+        lc = {}
+        for i, kind in enumerate(_lead_kinds(cfg)):
+            c = caches["lead"][f"pos{i}"]
+            h, new, st = block_decode(kind, params["lead"][f"pos{i}"], h, c,
+                                      pos, cfg, ctx, dense=True)
+            lc[f"pos{i}"] = write(c, new)
+            stats.append(st)
+        out_caches["lead"] = lc
+
     def unit_fn(hh, xs):
         unit_params, unit_cache = xs
-        new = {}
+        new, st = {}, []
         for j, kind in enumerate(pat):
-            hh, new[f"pos{j}"] = block_decode(
+            hh, new[f"pos{j}"], s = block_decode(
                 kind, unit_params[f"pos{j}"], hh, unit_cache[f"pos{j}"], pos, cfg, ctx
             )
             hh = CTX.constrain(hh)
-        return hh, new
+            st.append(s)
+        return hh, (new, st)
 
-    h, new_units = jax.lax.scan(unit_fn, h, (params["units"], caches["units"]))
-    out_caches = {"units": {
+    h, (new_units, unit_stats) = jax.lax.scan(
+        unit_fn, h, (params["units"], caches["units"]))
+    stats.extend(jax.tree.map(jnp.sum, unit_stats))
+    out_caches["units"] = {
         k: write(c, new_units[k]) for k, c in caches["units"].items()
-    }}
+    }
     if tail:
         tc = {}
         for i, kind in enumerate(tail):
             c = caches["tail"][f"pos{i}"]
-            h, new = block_decode(kind, params["tail"][f"pos{i}"], h, c, pos, cfg, ctx)
+            h, new, st = block_decode(kind, params["tail"][f"pos{i}"], h, c,
+                                      pos, cfg, ctx)
             tc[f"pos{i}"] = write(c, new)
+            stats.append(st)
         out_caches["tail"] = tc
     h = _norm(params["final_norm"], h, cfg)
-    return _head_public(params, cfg, h), out_caches
+    logits = _head_public(params, cfg, h)
+    if not with_stats:
+        return logits, out_caches
+    total = {}
+    for st in stats:
+        for k, v in st.items():
+            total[k] = total.get(k, 0) + v
+    return logits, out_caches, total

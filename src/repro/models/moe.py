@@ -1,8 +1,29 @@
-"""Mixture-of-Experts FFN: top-k routing with sort-based capacity dispatch.
+"""Mixture-of-Experts FFN: top-k routing, a dropless dispatch for serving
+and a sort-based capacity dispatch for training.
 
-The dispatch itself is reduction-as-matmul in the paper's spirit: tokens are
-gathered per-expert into a dense (E, C, d) block so the expert FFNs run as
-batched MXU einsums, and the combine is a gate-weighted segment reduction.
+Serving (``moe_apply_dropless``, prefill and decode) drops no token: the
+routed (token, expert) pairs are sorted by expert, the held experts' pairs
+form contiguous groups that grouped matmuls (``jax.lax.ragged_dot``) run
+over, and each token's pairs are combined in a fixed order (slot 0 to
+k - 1). A request's output therefore never depends on what the other
+requests of its wave route to. Training (``moe_apply``) keeps the
+per-sequence capacity dispatch below: its gradients flow through fixed
+(E, C) blocks, and the load-balance loss keeps drops rare.
+
+Both route over all ``n_experts`` router outputs. The dropless dispatch
+computes only the experts this device holds (``MoEConfig.held_start`` /
+``n_held``); a pair routed to an expert held elsewhere adds nothing here.
+The capacity dispatch requires every expert held. Gates are the
+softmax's top-k, renormalised when ``norm_topk_prob``, else scaled by
+``routed_scaling_factor``. Shared experts, when configured, act on every
+token as one SwiGLU. Scopes: ``moe_router`` (router, softmax, top-k, the
+sort), ``moe_experts`` (the routed experts and their combine),
+``moe_shared``.
+
+The capacity dispatch itself is reduction-as-matmul in the paper's spirit:
+tokens are gathered per-expert into a dense (E, C, d) block so the expert
+FFNs run as batched MXU einsums, and the combine is a gate-weighted segment
+reduction.
 Router softmax and the load-balance statistics (per-expert token fractions,
 mean gate mass -- arithmetic reductions over all tokens) ride the MMA path.
 
@@ -53,12 +74,12 @@ USE_SHARD_MAP_DISPATCH = False
 def moe_init(key, cfg):
     e = cfg.moe
     d = cfg.d_model
-    ks = P.split(key, 4)
+    ks = P.split(key, 5)
     dt = jnp.dtype(cfg.dtype)
 
     def expert_w(key, din, dout):
         return (
-            jax.random.normal(key, (e.n_experts, din, dout), jnp.float32) * din**-0.5
+            jax.random.normal(key, (e.n_held, din, dout), jnp.float32) * din**-0.5
         ).astype(dt)
 
     params = {
@@ -77,7 +98,115 @@ def moe_init(key, cfg):
     if cfg.ffn_kind != "swiglu":
         params.pop("gate")
         axes.pop("gate")
+    if e.n_shared_experts:
+        params["shared"], axes["shared"] = L.ffn_init(
+            ks[4], d, e.n_shared_experts * e.d_ff_expert, cfg.ffn_kind, dt)
     return params, axes
+
+
+@jax.named_scope("moe_router")
+def route(p, x, cfg):
+    """x: (..., d) -> (logits and probs (..., E), gates and experts
+    (..., k)). The router runs in f32 at full precision: a near tie
+    rounded to bf16 would pick another expert."""
+    e = cfg.moe
+    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32), p["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = L.softmax_mma(logits, mma=cfg.mma_reductions)
+    gate_vals, expert_ix = jax.lax.top_k(probs, e.top_k)
+    if e.norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(
+            jnp.sum(gate_vals, -1, keepdims=True), 1e-9
+        )
+    else:
+        gate_vals = gate_vals * e.routed_scaling_factor
+    return logits, probs, gate_vals, expert_ix
+
+
+@jax.named_scope("moe_shared")
+def _shared(p, x, cfg):
+    return L.ffn_apply(p["shared"], x, cfg.ffn_kind)
+
+
+def held_mask(expert_ix, cfg):
+    """Which routed pairs this device computes: their expert is held here."""
+    e = cfg.moe
+    local = expert_ix - e.held_start
+    return (local >= 0) & (local < e.n_held)
+
+
+# Tokens per grouped-matmul pass of the dropless dispatch: a prefill's
+# tokens go through in chunks, so its sorted pair rows (chunk * k of them)
+# stay small beside the caches.
+DROPLESS_CHUNK = 8192
+
+
+def _experts_dropless(p, x, gates, expert_ix, cfg):
+    """The held experts' part of the routed output for x: (T, d) tokens with
+    gates and experts (T, k). Returns (y (T, d), routed pairs on held
+    experts)."""
+    e = cfg.moe
+    t, k = expert_ix.shape
+    held = held_mask(expert_ix, cfg)
+    with jax.named_scope("moe_router"):
+        # held experts' pairs first, grouped by expert; the rest last
+        key = jnp.where(held, expert_ix - e.held_start, e.n_held).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros((e.n_held + 1,), jnp.int32).at[key].add(1)[:-1]
+    with jax.named_scope("moe_experts"):
+        rows = x[order // k]                                   # (T*k, d)
+        dt = x.dtype
+
+        def gmm(a, w):
+            return jax.lax.ragged_dot(a, w.astype(dt), sizes,
+                                      preferred_element_type=jnp.float32)
+
+        if cfg.ffn_kind == "swiglu":
+            h = jax.nn.silu(gmm(rows, p["gate"])) * gmm(rows, p["up"])
+        else:
+            h = jax.nn.gelu(gmm(rows, p["up"]))
+        out = gmm(h.astype(dt), p["down"])                     # (T*k, d)
+        # back to (token, slot) order; rows past the held groups are unset
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.size, dtype=order.dtype))
+        pairs = out[inv].reshape(t, k, -1)
+        y = jnp.zeros((t, x.shape[-1]), jnp.float32)
+        for j in range(k):                                     # fixed order
+            y = y + jnp.where(held[:, j, None],
+                              pairs[:, j] * gates[:, j, None], 0.0)
+    return y.astype(dt), jnp.sum(held, dtype=jnp.int32)
+
+
+def moe_apply_dropless(p, x, cfg):
+    """x: (B, S, d) -> (y, {"moe_held_pairs": routed pairs computed here}).
+
+    Every routed pair is computed (no capacity), on this device's held
+    experts; tokens go through ``DROPLESS_CHUNK`` at a time."""
+    e = cfg.moe
+    b, s, d = x.shape
+    xt = x.reshape(b * s, d)
+    _, _, gates, experts = route(p, xt, cfg)
+    n = b * s
+    if n <= DROPLESS_CHUNK:
+        y, pairs = _experts_dropless(p, xt, gates, experts, cfg)
+    else:
+        c = -(-n // DROPLESS_CHUNK)
+        pad = c * DROPLESS_CHUNK - n
+
+        def chunked(a, fill):
+            a = jnp.concatenate(
+                [a, jnp.full((pad,) + a.shape[1:], fill, a.dtype)], 0)
+            return a.reshape((c, DROPLESS_CHUNK) + a.shape[1:])
+
+        # padding tokens route to no held expert: they add nothing
+        ys, counts = jax.lax.map(
+            lambda a: _experts_dropless(p, *a, cfg),
+            (chunked(xt, 0), chunked(gates, 0), chunked(experts, -1)))
+        y, pairs = ys.reshape(-1, d)[:n], jnp.sum(counts)
+    y = y.reshape(b, s, d)
+    if e.n_shared_experts:
+        y = y + _shared(p, x, cfg)
+    return y, {"moe_held_pairs": pairs}
 
 
 def _dispatch_row(expert_ix, gate_vals, n_experts: int, cap: int, backend=None):
@@ -123,13 +252,9 @@ def moe_apply(p, x, cfg):
     (B, E, C, d) einsums sharded batch->data, experts->model (EP). Capacity-
     dropped tokens pass through the residual unchanged."""
     e = cfg.moe
+    assert e.holds_all, "a held share of the experts is served, not trained"
     b, s, d = x.shape
-    logits = x.astype(jnp.float32) @ p["router"]             # (B, S, E)
-    probs = L.softmax_mma(logits, mma=cfg.mma_reductions)
-    gate_vals, expert_ix = jax.lax.top_k(probs, e.top_k)     # (B, S, k)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, -1, keepdims=True), 1e-9
-    )
+    logits, probs, gate_vals, expert_ix = route(p, x, cfg)  # (B,S,E), (B,S,k)
     cap = int(max(1, round(s * e.top_k / e.n_experts * e.capacity_factor)))
 
     # Dispatch offsets route through the engine scan. The site is vmapped,
@@ -227,4 +352,7 @@ def moe_apply(p, x, cfg):
         "moe_z": zloss * e.router_z_weight,
         "moe_drop_frac": 1.0 - jnp.sum(keep) / keep.size,
     }
-    return y.astype(x.dtype), metrics
+    y = y.astype(x.dtype)
+    if e.n_shared_experts:
+        y = y + _shared(p, x, cfg)
+    return y, metrics

@@ -121,8 +121,10 @@ class ServeMetrics(GuardMetrics):
     completed requests/tokens, slot-steps run and slot-steps that received
     a token (``slot_steps``/``live_slot_steps``), the engine's decode calls
     that wrote their donated caches in place (``decode_in_place``, read
-    from the engine at every export), and the time to first
-    token and gaps between tokens of the newest ``request_window``
+    from the engine at every export), for a MoE engine the routed pairs
+    its decode calls computed on the device's held experts and those calls
+    (``moe_held_pairs``, ``moe_decode_calls``, read alike), and the time
+    to first token and gaps between tokens of the newest ``request_window``
     requests, summarized as p50/p99 in the snapshot. Everything exports
     through the same atomic-JSON ``write()`` (``--status-path``) the
     training supervisor uses, so one watchdog polls both shapes."""
@@ -141,6 +143,8 @@ class ServeMetrics(GuardMetrics):
         self.slot_steps = 0
         self.live_slot_steps = 0
         self.decode_in_place = 0
+        self.moe_held_pairs = 0
+        self.moe_decode_calls = 0
         self.breaker_states: dict = {}
         # (ttft, [gaps]) of the newest requests: a long-running server's
         # tail stays current, not lifetime-averaged
@@ -205,6 +209,8 @@ class ServeMetrics(GuardMetrics):
                 "slot_steps": self.slot_steps,
                 "live_slot_steps": self.live_slot_steps,
                 "decode_in_place": self.decode_in_place,
+                "moe_held_pairs": self.moe_held_pairs,
+                "moe_decode_calls": self.moe_decode_calls,
                 "ttft_p50_s": _percentile(ttft, 0.50),
                 "ttft_p99_s": _percentile(ttft, 0.99),
                 "itl_p50_s": _percentile(itl, 0.50),

@@ -657,7 +657,8 @@ class ServingRuntime:
 
     def _export(self) -> None:
         self.metrics.breaker_trips = self.breaker.total_trips
-        self.metrics.decode_in_place = getattr(self.engine, "decode_in_place", 0)
+        for name in ("decode_in_place", "moe_held_pairs", "moe_decode_calls"):
+            setattr(self.metrics, name, getattr(self.engine, name, 0))
         self.metrics.record_breaker_states(self.breaker.states())
         if self.status_path is not None:
             self.metrics.write(self.status_path)

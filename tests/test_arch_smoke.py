@@ -123,6 +123,7 @@ def test_full_config_is_published_dims(arch):
         "granite-moe-1b-a400m": (24, 1024, 49155),
         "olmo-1b": (16, 2048, 50304),
         "deepseek-7b": (30, 4096, 102400),
+        "deepseek-v2-lite": (27, 2048, 102400),
         "minicpm3-4b": (62, 2560, 73448),
         "internlm2-1.8b": (24, 2048, 92544),
         "recurrentgemma-9b": (38, 4096, 256000),

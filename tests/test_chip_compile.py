@@ -293,3 +293,58 @@ def test_guarded_decode_step_writes_cache_in_place_on_v5e(one_chip,
                                           for a in leaves)
     layer_kv = max(a.size * a.dtype.itemsize for a in leaves) // cfg.n_layers
     assert mem.temp_size_in_bytes < layer_kv // 8, mem.temp_size_in_bytes
+
+
+def test_deepseek_v2_lite_guarded_decode_writes_cache_in_place_on_v5e(
+        one_chip, compiled_kernels):
+    """The guarded decode step at deepseek-v2-lite's widths (its dense
+    first layer and two MoE layers, 8 experts held) with the chat cell's
+    128 slots of 1,025 positions: every MLA cache leaf, the leading
+    layer's included, is aliased to an output, no instruction copies the
+    latent cache or a layer's slice of it, and the temporaries stay under
+    one layer's bfloat16 latent (the normalised latent the dots read; a
+    cache of 576-wide entries laid out batch-minor copies each layer's
+    slice and needs more than twice that)."""
+    import re
+
+    from repro.launch.serve import GuardedEngine
+    from repro.models import make_caches
+    from repro.models.model import split_caches
+
+    full = get_arch("deepseek-v2-lite")
+    cfg = dataclasses.replace(full, n_layers=3, moe=dataclasses.replace(
+        full.moe, held_count=8))
+    slots, s_max = 128, 1025
+    eng = object.__new__(GuardedEngine)  # its shapes only: no weights made
+    eng.cfg, eng.s_max, eng.slots, eng.ctx = cfg, s_max, slots, None
+    eng._guarded_decode = {}
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda k: init_params(k, cfg)[0], jax.random.PRNGKey(0)))
+    indexed, rest = split_caches(on_chip(jax.eval_shape(
+        lambda: make_caches(cfg, slots, s_max))))
+    assert set(indexed) == {"lead", "units"}
+    lowered = eng._decode_fn("pallas_fused").lower(
+        params, indexed, rest,
+        jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((slots,), jnp.float32, sharding=one_chip))
+    leaves = jax.tree.leaves(indexed)
+    assert len(leaves) == 6
+    assert lowered.as_text().count("tf.aliasing_output") == len(leaves)
+    compiled = lowered.compile()
+    _assert_kernel(compiled)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize
+                                          for a in leaves)
+    stacked = indexed["units"]["pos0"]["ckv"]
+    layer_shape = ",".join(str(d) for d in stacked.shape[1:])
+    copies = re.findall(r"= (\w+\[[0-9,]*\])[^ ]* copy\(", compiled.as_text())
+    assert not [c for c in copies if c.endswith(f"{layer_shape}]")], copies
+    layer = stacked.size // stacked.shape[0] * 2        # one layer, bf16
+    assert mem.temp_size_in_bytes < layer, mem.temp_size_in_bytes

@@ -20,6 +20,7 @@ CASES = {
     "olmo-1b": 8,             # full K/V
     "recurrentgemma-9b": 20,  # ring K/V beside RG-LRU states
     "minicpm3-4b": 8,         # MLA latent
+    "deepseek-v2-lite": 8,    # MLA latent, a leading dense layer's too
     "mamba2-780m": 8,         # SSM state, nothing indexed by position
 }
 MAX_NEW = 4
@@ -42,7 +43,8 @@ def _decode_args(eng, state, scales):
             jnp.asarray(scales, jnp.float32))
 
 
-@pytest.mark.parametrize("arch", ["olmo-1b", "recurrentgemma-9b"])
+@pytest.mark.parametrize("arch", ["olmo-1b", "recurrentgemma-9b",
+                                  "deepseek-v2-lite"])
 def test_guarded_decode_donates_position_indexed_caches(arch):
     eng, prompts = _engine(arch)
     ones = [1.0] * eng.slots

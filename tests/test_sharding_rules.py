@@ -111,3 +111,33 @@ def test_collective_depth_attribution():
     assert d["0"] == 2 * 256 * 4                 # entry AR, wire x2
     assert d["1"] == 16 * 128 * 4                # AG in the depth-1 body
     assert d["2"] == 2 * 8 * 128 * 4             # AR in the depth-2 body
+
+
+def test_deepseek_v2_lite_param_and_cache_specs(mesh):
+    """DeepSeek-V2-Lite's new leaves take the existing logical axes: the
+    direct query projection shards heads, routed experts go expert
+    parallel, the shared experts shard like a dense feed-forward; the
+    leading dense layer's latent cache has no layer axis, the stacked
+    layers' has an unsharded one."""
+    from repro.configs import ARCHS, TINY_ARCHS
+    from repro.models import init_params, make_caches
+
+    cfg = ARCHS["deepseek-v2-lite"]
+    shapes = jax.eval_shape(lambda k: init_params(k, cfg)[0],
+                            jax.random.PRNGKey(0))
+    _, axes = init_params(jax.random.PRNGKey(0), TINY_ARCHS["deepseek-v2-lite"])
+    ffn = axes["units"]["pos0"]["ffn"]
+    q = axes["lead"]["pos0"]["mix"]["q"]["w"]
+    assert spec(q) == P("data", "model")
+    assert spec(ffn["gate"][1:]) == P("model", "data")
+    assert spec(ffn["shared"]["gate"]["w"][1:]) == P("data", "model")
+    assert "router" in ffn and "shared" in ffn
+    assert "q_down" not in axes["lead"]["pos0"]["mix"]
+    e = shapes["units"]["pos0"]["ffn"]["gate"]
+    assert e.shape == (26, 64, 2048, 1408)
+    caches = jax.eval_shape(
+        lambda: make_caches(TINY_ARCHS["deepseek-v2-lite"], 2, 16))
+    specs = SH.cache_shardings(caches, TINY_ARCHS["deepseek-v2-lite"], mesh)
+    for leaf in ("ckv", "k_rope"):
+        assert len(specs["lead"]["pos0"][leaf].spec) <= 3
+        assert specs["units"]["pos0"][leaf].spec[0] is None
