@@ -44,10 +44,11 @@ build failures instead of silent drift:
      whole jitted guarded update (bitwise skip + spike detector) lowers
      with no ``is_finite``/``select_n`` outside the kernel.
   7. SERVE GUARD -- the census-guarded DECODE statistic
-     (``runtime.serving.guarded_logit_stat`` and the single-array
-     ``reduce(..., census=True)``) is one pallas_call on both Pallas
-     backends, census-free in lowering, and reads exactly the bytes the
-     unguarded statistic reads (``--serve`` runs it standalone).
+     (``runtime.serving.guarded_logit_stat``, one segmented launch over
+     the slots' rows, and the single-array ``reduce(..., census=True)``)
+     is one pallas_call on both Pallas backends, census-free in lowering,
+     and reads exactly the bytes the unguarded statistic reads
+     (``--serve`` runs it standalone).
   8. MMA SCAN -- the triangular-MMA prefix sum is one pallas_call at
      every lane count, staging-free on bf16 ingest (incl. reverse /
      exclusive), its trace-counted MMA rows (``mma_scan_262k_c{c}``)
@@ -427,14 +428,15 @@ def check_serve_guard() -> None:
 
       a. ``runtime.serving.guarded_logit_stat`` (per-slot sumsq + per-slot
          non-finite census over one decode step's logits) is EXACTLY one
-         pallas_call on both Pallas backends -- the per-slot statistic,
-         the cross-slot total, and the census all ride one launch;
+         pallas_call on both Pallas backends -- the per-slot statistic and
+         the census ride one segmented launch over the slots' rows;
       b. the lowering is census-free: NO ``is_finite``/``select_n`` of any
          size outside the pallas_call (the guard is the in-kernel second
          accumulator, not a host-side mask pass);
-      c. measured launch-boundary bytes == the parts model widened by the
-         census slots, and the KERNEL-READ side is byte-identical to the
-         UNGUARDED statistic's lowering -- the guard adds (slots+1) f32
+      c. measured launch-boundary bytes == the segmented model widened by
+         the census slots, and the KERNEL-READ side is byte-identical to
+         the UNGUARDED row statistic's lowering (the same segmented launch
+         without the census) -- the guard adds its per-slot f32 count
          OUTPUT slots only, zero extra kernel input bytes;
       d. the single-array form ``reduce(x, census=True)`` holds the same
          three properties (one launch, census-free, read-identical).
@@ -446,34 +448,38 @@ def check_serve_guard() -> None:
     from repro.reduce import inspect as rinspect
     from repro.runtime.serving import guarded_logit_stat
 
-    slots, vocab = 4, 4096
+    # one whole m^2 tile a slot: the cover fetches every byte once, so the
+    # lowered operand bytes equal the model's fetched bytes exactly
+    slots, vocab = 4, 16384
     logits = jnp.ones((slots, 1, vocab), jnp.float32)
     n = logits.size
-    plain = cost_model.hbm_bytes("parts", n, 4, segments=slots + 1)
-    want = cost_model.hbm_bytes("parts", n, 4, segments=slots + 1,
-                                census=slots + 1)
+    offsets = tuple(range(0, n + 1, vocab))
+    plain = cost_model.hbm_bytes("segmented", n, 4, segments=slots,
+                                 tiles=slots)
+    want = cost_model.hbm_bytes("segmented", n, 4, segments=slots,
+                                census=slots, tiles=slots)
     assert want.kernel_read == plain.kernel_read, (want, plain)
     for backend in ("pallas_fused", "pallas_hier"):
         guarded = lambda lg, b=backend: guarded_logit_stat(lg, backend=b)
-        unguarded = lambda lg, b=backend: R.reduce_tree(
-            [lg[i] for i in range(lg.shape[0])], "sumsq", backend=b,
-            return_per_leaf=True,
-        )
+        plan = R.plan_for((n,), jnp.float32, kind="sumsq", backend=backend,
+                          segments=slots)
+        unguarded = lambda lg, b=backend, p=plan: R.get_backend(
+            b).sum_segments(lg.reshape(-1), offsets, p, "square")
         nc = rinspect.count_pallas_calls(guarded, logits)
         assert nc == 1, f"guarded decode stat[{backend}]: {nc} pallas_calls"
         rinspect.assert_census_free(guarded, logits)  # (b)
         measured = rinspect.pallas_io_bytes(jax.make_jaxpr(guarded)(logits))
         assert measured == want.launch_io, (backend, measured, want)  # (c)
         base = rinspect.pallas_io_bytes(jax.make_jaxpr(unguarded)(logits))
-        # the guard's whole cost: (slots + 1) f32 census OUTPUT slots
-        assert measured - base == (slots + 1) * 4, (backend, measured, base)
+        # the guard's whole cost: one f32 census OUTPUT slot per slot
+        assert measured - base == slots * 4, (backend, measured, base)
         assert want.kernel_read == plain.kernel_read  # reads identical
 
         # (d) the single-array serving guard: reduce(x, census=True). Its
         # baseline is the same parts-kernel lowering WITHOUT the census
         # (reduce_tree's one-leaf fork) -- the plain reduce() rides the
         # single-operand kernel whose block padding differs by design.
-        x = jnp.ones((n,), jnp.bfloat16)
+        x = jnp.ones((16384,), jnp.bfloat16)
         one = lambda v, b=backend: R.reduce(v, kind="sumsq", census=True,
                                             backend=b)
         nc = rinspect.count_pallas_calls(one, x)
@@ -489,8 +495,9 @@ def check_serve_guard() -> None:
         )
         # the guard's whole cost: 2 census slots (part count + total)
         assert m1 - m0 == 2 * 4, (backend, m1, m0)
-        want1 = cost_model.hbm_bytes("parts", n, 2, segments=2, census=2)
-        plain1 = cost_model.hbm_bytes("parts", n, 2, segments=2)
+        want1 = cost_model.hbm_bytes("parts", x.size, 2, segments=2,
+                                     census=2)
+        plain1 = cost_model.hbm_bytes("parts", x.size, 2, segments=2)
         assert m1 == want1.launch_io, (backend, m1, want1)
         assert want1.kernel_read == plain1.kernel_read, (want1, plain1)
     print(

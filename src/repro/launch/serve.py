@@ -17,9 +17,10 @@ Two paths share the jitted steps:
   GuardedEngine + runtime.ServingRuntime -- the resilient path (--guard):
                          bounded admission, per-request deadlines, the
                          census-guarded decode (every step's logit
-                         statistic rides ``reduce_tree(census=True)`` --
-                         NaN/Inf detected in the SAME launch, per slot,
-                         zero extra kernel input bytes), and the
+                         statistic rides the row census ``reduce(...,
+                         axis=-1, census=True)`` -- NaN/Inf detected in
+                         the SAME launch, per slot, zero extra kernel
+                         input bytes), and the
                          per-backend circuit breaker degrading
                          pallas -> mma_jnp -> xla under kernel faults.
 
@@ -149,12 +150,13 @@ class GuardedEngine(Engine):
     Each step is one jitted function per (stat) backend: model decode +
     the chaos scale multiply (x1.0 = bitwise identity) + the per-slot
     logit statistic with its in-launch non-finite census
-    (``guarded_logit_stat`` -- one pallas_call on the kernel backends,
-    zero input bytes beyond the logits the statistic already reads) + the
-    greedy argmax. Keying the jitted functions by backend NAME (not the
-    process default) is what makes the breaker's re-route safe under jit
-    -- a traced computation has its plan baked in, so each backend gets
-    its own trace.
+    (``guarded_logit_stat`` -- on the kernel backends one pallas_call of
+    the segmented gather kernel over the slots' rows of the logits, with
+    one compiled body at any slot count and zero input bytes beyond the
+    logits the statistic already reads) + the greedy argmax. Keying the
+    jitted functions by backend NAME (not the process default) is what
+    makes the breaker's re-route safe under jit -- a traced computation
+    has its plan baked in, so each backend gets its own trace.
 
     A decode step donates the wave's position-indexed caches and writes
     position ``pos`` of them in place; ``decode`` moves the written

@@ -42,7 +42,12 @@ import numpy as np
 
 from repro.kernels import common as _kcommon
 from repro.reduce import backends as _backends
-from repro.reduce.plan import ReducePlan, norm_mesh_axes, plan_for
+from repro.reduce.plan import (
+    ReducePlan,
+    norm_mesh_axes,
+    plan_for,
+    segmented_backend_for,
+)
 
 Axis = Union[None, int, Sequence[int]]
 
@@ -579,6 +584,74 @@ def _reduce_census_full(x: jax.Array, kind: str, p: ReducePlan, chain: tuple):
     return row[1], row[3]
 
 
+# Row census: B equal rows of one 2-D array, each row's statistic and its
+# NaN/Inf count from ONE segmented launch over the flat buffer (the kernel
+# backends). The launch returns (2B,) -- row sums, then row counts -- and the
+# custom VJP maps the row cotangents back through the prologue's chain rule;
+# the count slots are piecewise constant, so their cotangents drop.
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _ksum_rows_census(x: jax.Array, plan: ReducePlan, prologue: str):
+    rows, width = x.shape
+    backend = _backends.get_backend(plan.backend)
+    return backend.sum_segments(
+        x.reshape(-1), tuple(range(0, rows * width + 1, width)), plan,
+        prologue, census=True,
+    ).astype(plan.accum_jnp)
+
+
+def _krows_fwd(x, plan, prologue):
+    res = x if prologue != "identity" else jnp.zeros((0,) + x.shape, x.dtype)
+    return _ksum_rows_census(x, plan, prologue), res
+
+
+def _krows_bwd(plan, prologue, res, g):
+    g_rows = g[: res.shape[-2], None]
+    if prologue == "identity":
+        return (jnp.broadcast_to(g_rows, res.shape[1:]).astype(res.dtype),)
+    return ((2.0 * res.astype(plan.accum_jnp) * g_rows).astype(res.dtype),)
+
+
+_ksum_rows_census.defvjp(_krows_fwd, _krows_bwd)
+
+
+def _reduce_census_rows(x: jax.Array, kind: str, p: ReducePlan):
+    """Per-row statistic + per-row non-finite census of a 2-D ``x``.
+
+    Returns ``(per_row (B,), counts (B + 1,))``: the counts are each row's
+    NaN/Inf elements with their total appended. On the kernel backends the
+    B rows are B equal segments of the one flat buffer, so the whole census
+    is one launch of the segmented gather kernel whose compiled body does
+    not depend on B (its cover maps are scalar-prefetched). The total is a
+    plain sum of the B counts. The jnp-level backends compute the same
+    values as fusible code: the row statistic as the eq. (9) dot, the
+    counts as a row sum of the non-finite mask."""
+    accum = p.accum_jnp
+    rows, width = x.shape
+    square = kind in ("sumsq", "norm2")
+    if p.backend == "segmented":
+        p = p.replace(backend=segmented_backend_for(x.size, x.dtype, p.m))
+    if x.size == 0:
+        per = counts = jnp.zeros((rows,), accum)
+    elif _backends.get_backend(p.backend).native_prologue:
+        out = _ksum_rows_census(x, p, "square" if square else "identity")
+        per, counts = out[:rows], out[rows:]
+    else:
+        xf = x.astype(accum)
+        per = _sum(xf * xf if square else xf, (1,), p)
+        counts = (
+            jnp.sum(~jnp.isfinite(x), axis=-1).astype(accum)
+            if jnp.issubdtype(x.dtype, jnp.inexact)
+            else jnp.zeros((rows,), accum)
+        )
+    if kind == "mean":
+        per = per * (1.0 / width if width else float("nan"))
+    elif kind == "norm2":
+        per = jnp.sqrt(per)
+    return per, jnp.concatenate([counts, jnp.sum(counts)[None]])
+
+
 def reduce(
     x,
     axis: Axis = None,
@@ -647,13 +720,16 @@ def reduce(
     scalar in plan.accum_dtype. On the kernel backends the count rides the
     second in-kernel accumulator over the tiles already streaming -- zero
     extra HBM input bytes, exactly the ``reduce_tree(census=True)``
-    machinery restricted to one leaf -- so a serving engine's per-step
-    logit statistic doubles as its non-finite detector for free. FULL
-    reductions only (axis=None), kinds sum/mean/sumsq/norm2; composes with
-    ``epilogue`` (the chain finishes the statistic, the count is raw) and
-    with ``mesh_axes`` (both halves ride the one fixed-order combine). The
-    count tallies INPUT elements only -- an empty mean's definitional NaN
-    never increments it.
+    machinery restricted to one leaf. Kinds sum/mean/sumsq/norm2; a FULL
+    reduction composes with ``epilogue`` (the chain finishes the
+    statistic, the count is raw) and with ``mesh_axes`` (both halves ride
+    the one fixed-order combine). On a 2-D ``x`` with ``axis=-1`` the
+    census is per row: the return is ``(per_row (B,), counts (B + 1,))``,
+    each row's count with their total appended, and on the kernel backends
+    it is ONE segmented launch over the B equal rows whatever B is -- a
+    serving engine's per-slot logit statistic and its non-finite detector
+    (``runtime.serving.guarded_logit_stat``). The count tallies INPUT
+    elements only -- an empty mean's definitional NaN never increments it.
 
     ``mesh_axes`` (an axis name or tuple of names, bound by an enclosing
     ``shard_map``) makes a FULL reduction global across the mesh: the local
@@ -670,11 +746,16 @@ def reduce(
     x = jnp.asarray(x)
     axis_t = _normalize_axis(axis, x.ndim)
     if census:
-        if axis_t is not None:
+        if axis_t is not None and (x.ndim != 2 or axis_t != (1,)):
             raise ValueError(
-                "census=True applies to FULL reductions (axis=None): the "
-                "count shares the statistic's launch; got axis="
-                f"{axis!r}"
+                "census=True applies to FULL reductions (axis=None) or to "
+                "the rows of a 2-D array (axis=-1): the count shares the "
+                f"statistic's launch; got axis={axis!r} on ndim {x.ndim}"
+            )
+        if axis_t is not None and mesh_axes is not None:
+            raise ValueError(
+                "the row census is a single-device reduction; mesh_axes= "
+                "applies to FULL reductions"
             )
         if kind == "moments":
             raise ValueError(
@@ -693,6 +774,15 @@ def reduce(
                 "epilogue chains do not compose with kind='moments' (two "
                 "coupled outputs); chain the statistic you need instead"
             )
+    if census and axis_t is not None:
+        # B rows are a segmented multi-reduce of B equal pieces: plan the
+        # flat stream, as reduce_many does
+        probe = jax.ShapeDtypeStruct((x.size,), x.dtype)
+        p = _resolve_plan(probe, None, kind, plan, backend, m,
+                          tiles_per_block, compute_dtype, accum_dtype,
+                          precision, kahan_block, segments=x.shape[0],
+                          num_cores=num_cores)
+        return _reduce_census_rows(x, kind, p)
     p = _resolve_plan(x, axis_t, kind, plan, backend, m, tiles_per_block,
                       compute_dtype, accum_dtype, precision, kahan_block,
                       num_cores=num_cores, mesh_axes=mesh_axes)

@@ -653,7 +653,7 @@ class _PallasBackend(Backend):
         )
 
     def sum_segments(self, flat, offsets, plan, prologue="identity",
-                     epilogue=()):
+                     epilogue=(), census=False):
         # Both kernel modes share the single-launch segmented gather kernel:
         # the hierarchy's only distinction (relaunch on partials) is moot
         # once every boundary flushes inside one launch. The kernel reads
@@ -661,6 +661,9 @@ class _PallasBackend(Backend):
         # each gathered tile in-kernel; ``epilogue`` maps each flushed
         # per-segment total (in-kernel on single-lane launches, host-side
         # after the lane combine otherwise -- same chain, same values).
+        # ``census=True`` widens the result to (2S,): the per-segment
+        # NaN/Inf counts follow the sums, counted on the same gather (the
+        # row census behind ``reduce(x, axis=-1, census=True)``).
         self._check_m(plan)
         out = _pallas_ops.mma_sum_segments_pallas(
             flat,
@@ -670,6 +673,7 @@ class _PallasBackend(Backend):
             compute_dtype=plan.compute_jnp,
             prologue=prologue,
             epilogue=epilogue,
+            census=census,
         )
         return out.astype(plan.accum_jnp)
 

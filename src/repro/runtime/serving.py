@@ -13,8 +13,8 @@ the same primitives:
                     returning ``RequestRejected`` instead of queueing a
                     guaranteed miss.
   census guard   -- every decode step's logit statistic rides
-                    ``reduce(..., census=True)`` / ``reduce_tree``'s
-                    per-slot fork (``guarded_logit_stat``): the SAME launch
+                    the row census ``reduce(..., axis=-1, census=True)``
+                    (``guarded_logit_stat``): the SAME launch
                     that computes the statistic counts NaN/Inf logits per
                     slot, zero extra HBM input bytes. A poisoned slot is
                     quarantined for the step and the step retried WITHOUT
@@ -348,28 +348,26 @@ def _planner_close(name: str) -> None:
 def guarded_logit_stat(logits, *, backend: Optional[str] = None):
     """Per-slot logit sumsq + in-launch non-finite census, ONE launch.
 
-    ``logits``: (B, ...) -- slot-major decode logits. Each slot enters the
-    parts kernel as its own leaf, so the return is ``(stat, counts)``:
+    ``logits``: (B, ...) -- slot-major decode logits. Each slot's logits
+    are one row of the (B, V) view, so the return is ``(stat, counts)``:
     per-slot sum-of-squares (B,) and per-slot NaN/Inf counts with the
-    cross-slot total appended (B + 1,). On the Pallas backends this is one
-    ``pallas_call`` reading exactly the logits bytes the statistic alone
-    would read (the census rides the second in-kernel accumulator --
-    ``check_bench.check_serve_guard`` gates both properties); the census
+    cross-slot total appended (B + 1,) -- ``reduce(..., axis=-1,
+    census=True)``. On the Pallas backends this is one ``pallas_call`` of
+    the segmented gather kernel over the B equal rows of the flat logits,
+    whose compiled body is the same at any slot count; the census rides
+    its second in-kernel accumulator, so the launch reads no input byte
+    beyond the logits the statistic alone would read
+    (``check_bench.check_serve_guard`` gates both properties). The census
     tells the runtime WHICH slot to quarantine, not just that something is
     wrong. ``backend=None`` lets the planner choose (breaker-quarantined
     backends excluded); the breaker passes its selection explicitly."""
     from repro import reduce as R
 
     b = logits.shape[0]
-    leaves = [logits[i] for i in range(b)]
-    stat, _totals, counts = R.reduce_tree(
-        leaves,
-        "sumsq",
-        backend=backend,
-        return_per_leaf=True,
+    return R.reduce(
+        logits.reshape(b, -1), axis=-1, kind="sumsq", backend=backend,
         census=True,
     )
-    return stat, counts
 
 
 class ServingRuntime:

@@ -11,6 +11,11 @@ These tests pin:
   * gradients still flow through a census launch (counts are piecewise
     constant: their cotangents drop);
   * the direct kernel entry points (fused scalar, segmented);
+  * the row census behind the serving guard (``guarded_logit_stat``):
+    per-slot statistic and counts against numpy at slot counts up to and
+    past PARTS_KERNEL_MAX on every backend class, with poison in tiles
+    that straddle rows, and ONE launch whose kernel body does not grow
+    with the slots;
   * the empty-"mean" NaN is DEFINED, not a fault: the census never counts
     a statistic, only input elements (satellite: mean empty-input pin);
   * legacy Backend subclasses that predate the census parameter degrade to
@@ -253,6 +258,135 @@ def test_legacy_backend_without_census_param_degrades():
         np.asarray(legacy[-4:]), np.asarray(native[-4:])
     )
     np.testing.assert_array_equal(np.asarray(legacy[-4:]), [2.0, 0.0, 1.0, 3.0])
+
+
+# ------------- row census: the serving guard's per-slot statistic -------------
+
+# A vocabulary that is no multiple of the 16,384-element MMA tile, so tiles
+# straddle rows: row 5 starts inside tile 0, row 6 inside tile 1.
+ROW_VOCAB = 3000
+
+
+def _poisoned_logits(slots):
+    """(slots, 1, ROW_VOCAB) f32 logits with NaN at row 0's last element,
+    Inf in row 5 inside the tile that straddles rows 4|5, NaN at row 6's
+    first element inside the tile that straddles rows 5|6, and NaN at the
+    last row's last element (rows that exist only). Returns the logits and
+    each row's non-finite count."""
+    rng = np.random.default_rng(slots)
+    x = rng.standard_normal((slots, 1, ROW_VOCAB)).astype(np.float32)
+    for row, col, v in ((0, ROW_VOCAB - 1, np.nan), (5, 100, np.inf),
+                        (6, 0, np.nan), (slots - 1, ROW_VOCAB - 1, np.nan)):
+        if row < slots:
+            x[row, 0, col] = v
+    return x, (~np.isfinite(x.reshape(slots, -1))).sum(-1)
+
+
+@pytest.mark.parametrize("backend",
+                         ("pallas_fused", "pallas_hier", "mma_jnp", "xla"))
+@pytest.mark.parametrize("slots", (1, 48, 128, ops.PARTS_KERNEL_MAX + 1))
+def test_guarded_logit_stat_matches_numpy(slots, backend):
+    """Per-slot sumsq to f32 accuracy, per-slot NaN/Inf counts and their
+    total exactly, at slot counts up to and past PARTS_KERNEL_MAX."""
+    from repro.runtime.serving import guarded_logit_stat
+
+    x, counts = _poisoned_logits(slots)
+    stat, got = guarded_logit_stat(jnp.asarray(x), backend=backend)
+    assert stat.shape == (slots,) and got.shape == (slots + 1,)
+    np.testing.assert_array_equal(
+        np.asarray(got), np.append(counts, counts.sum())
+    )
+    rows = x.reshape(slots, -1).astype(np.float64)
+    clean = counts == 0
+    np.testing.assert_allclose(
+        np.asarray(stat)[clean], (rows[clean] ** 2).sum(-1), rtol=1e-5
+    )
+    assert not np.isfinite(np.asarray(stat)[~clean]).any()
+
+
+@pytest.mark.parametrize("backend", ("pallas_fused", "pallas_hier"))
+def test_guarded_logit_stat_one_body_at_any_slot_count(backend):
+    """One pallas_call of the segmented gather kernel, census-free around
+    it, whose kernel jaxpr holds as many equations at 8 slots as at 128
+    and 129: the compiled body does not grow with the slots (one operand
+    and one unrolled branch per slot did)."""
+    from repro.runtime.serving import guarded_logit_stat
+
+    def stat(lg):
+        return guarded_logit_stat(lg, backend=backend)
+
+    body_eqns = set()
+    for slots in (8, 128, ops.PARTS_KERNEL_MAX + 1):
+        lg = jnp.ones((slots, 1, ROW_VOCAB), jnp.float32)
+        calls = [
+            eqn for eqn, inside in rinspect.iter_eqns(jax.make_jaxpr(stat)(lg))
+            if not inside and eqn.primitive.name == "pallas_call"
+        ]
+        assert len(calls) == 1, (slots, len(calls))
+        body = calls[0].params["jaxpr"]
+        assert body.debug_info.func_src_info.startswith(
+            "segmented_gather_kernel"), body.debug_info.func_src_info
+        body_eqns.add(sum(1 for _ in rinspect.iter_eqns(body)))
+        rinspect.assert_census_free(stat, lg)
+    assert len(body_eqns) == 1, body_eqns
+
+
+@pytest.mark.parametrize("backend", ("pallas_fused", "mma_jnp"))
+@pytest.mark.parametrize("kind", ("sum", "mean", "norm2"))
+def test_reduce_row_census_kinds(kind, backend):
+    rng = np.random.default_rng(3)
+    x = rng.uniform(0.5, 1.5, (7, ROW_VOCAB)).astype(np.float32)
+    x[2, ROW_VOCAB - 1] = np.nan
+    x[5, 100] = np.inf
+    per, counts = R.reduce(jnp.asarray(x), axis=-1, kind=kind,
+                           backend=backend, census=True)
+    np.testing.assert_array_equal(
+        np.asarray(counts), [0, 0, 1, 0, 0, 1, 0, 2]
+    )
+    xf = x.astype(np.float64)
+    want = {"sum": xf.sum(-1), "mean": xf.mean(-1),
+            "norm2": np.sqrt((xf * xf).sum(-1))}[kind]
+    clean = [0, 1, 3, 4, 6]
+    # sum/mean multiply at bf16 by the planner's default
+    np.testing.assert_allclose(np.asarray(per)[clean], want[clean],
+                               rtol=1e-2 if kind != "norm2" else 1e-5)
+
+
+@pytest.mark.parametrize("shape", ((0, 5), (3, 0)))
+def test_reduce_row_census_empty(shape):
+    per, counts = R.reduce(jnp.zeros(shape, jnp.float32), axis=-1,
+                           kind="sumsq", backend="pallas_fused", census=True)
+    np.testing.assert_array_equal(np.asarray(per), np.zeros(shape[0]))
+    np.testing.assert_array_equal(np.asarray(counts), np.zeros(shape[0] + 1))
+
+
+def test_reduce_row_census_refusals():
+    with pytest.raises(ValueError, match="rows of a 2-D"):
+        R.reduce(jnp.ones((2, 3, 4)), axis=-1, kind="sumsq", census=True)
+    with pytest.raises(ValueError, match="rows of a 2-D"):
+        R.reduce(jnp.ones((3, 4)), axis=0, kind="sumsq", census=True)
+    with pytest.raises(ValueError, match="mesh_axes"):
+        R.reduce(jnp.ones((3, 4)), axis=-1, kind="sumsq", census=True,
+                 mesh_axes="d")
+    with pytest.raises(ValueError, match="moments"):
+        R.reduce(jnp.ones((3, 4)), axis=-1, kind="moments", census=True)
+
+
+@pytest.mark.parametrize("backend", ("pallas_fused", "xla"))
+@pytest.mark.parametrize("kind", ("sum", "sumsq"))
+def test_grads_flow_through_row_census(kind, backend):
+    x = jnp.linspace(-1.0, 1.0, 3 * 700, dtype=jnp.float32).reshape(3, 700)
+    w = jnp.asarray([1.0, 2.0, 3.0])
+
+    def loss(v):
+        per, _ = R.reduce(v, axis=-1, kind=kind, backend=backend, census=True)
+        return jnp.dot(per, w)
+
+    want = np.broadcast_to(np.asarray(w)[:, None], x.shape)
+    if kind == "sumsq":
+        want = 2.0 * np.asarray(x) * want
+    np.testing.assert_allclose(np.asarray(jax.grad(loss)(x)), want,
+                               rtol=1e-5, atol=1e-6)
 
 
 # --------------------- guarded optimizer (bitwise skip) ---------------------

@@ -304,7 +304,9 @@ def test_deepseek_v2_lite_guarded_decode_writes_cache_in_place_on_v5e(
     latent cache or a layer's slice of it, and the temporaries stay under
     one layer's bfloat16 latent (the normalised latent the dots read; a
     cache of 576-wide entries laid out batch-minor copies each layer's
-    slice and needs more than twice that)."""
+    slice and needs more than twice that) plus the scaled logits. The
+    census reads those logits as one flat f32 buffer where the scale
+    writes them: no instruction copies the logits to flatten them."""
     import re
 
     from repro.launch.serve import GuardedEngine
@@ -346,5 +348,10 @@ def test_deepseek_v2_lite_guarded_decode_writes_cache_in_place_on_v5e(
     layer_shape = ",".join(str(d) for d in stacked.shape[1:])
     copies = re.findall(r"= (\w+\[[0-9,]*\])[^ ]* copy\(", compiled.as_text())
     assert not [c for c in copies if c.endswith(f"{layer_shape}]")], copies
+    v = cfg.vocab_size
+    assert not [c for c in copies
+                if c in (f"f32[{slots},1,{v}]", f"f32[{slots * v}]")], copies
     layer = stacked.size // stacked.shape[0] * 2        # one layer, bf16
-    assert mem.temp_size_in_bytes < layer, mem.temp_size_in_bytes
+    scaled_logits = slots * v * 4                # the census's operand
+    assert mem.temp_size_in_bytes < layer + scaled_logits, \
+        mem.temp_size_in_bytes
